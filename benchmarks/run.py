@@ -33,6 +33,9 @@ def main() -> None:
     args = ap.parse_args()
     import importlib
 
+    from repro import compile_cache
+    compile_cache.enable()
+
     from benchmarks.common import emit
     print("name,us_per_call,derived")
     failed = []

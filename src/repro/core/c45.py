@@ -25,6 +25,7 @@ import dataclasses
 from collections import deque
 from typing import Callable
 
+import jax
 import numpy as np
 
 from repro.core import entropy
@@ -33,6 +34,11 @@ from repro.core.config import GrowConfig
 from repro.core.tree import Tree
 
 EPS_W = 1e-7
+
+# One compiled scorer per histogram shape: the node loop calls it once per
+# node, and op-by-op dispatch of the scorer's ordered sums would dominate.
+_gains = jax.jit(entropy.gains_from_histogram,
+                 static_argnames=("min_objs", "criterion"))
 
 
 @dataclasses.dataclass
@@ -134,7 +140,7 @@ def split_att(hist: np.ndarray, total_w: float, ds: BinnedDataset,
     Delegates to the shared jnp scorer so the oracle and the SPMD engine
     produce identical scores for identical histograms.
     """
-    score, split_bin = entropy.gains_from_histogram(
+    score, split_bin = _gains(
         hist,
         total_w=np.float32(total_w),
         attr_is_cont=ds.attr_is_cont,

@@ -30,9 +30,10 @@ class GrowConfig:
         O(live) instead of O(N) (see repro.kernels.compaction).
       compact_min_bucket: smallest gather bucket of the power-of-two ladder
         (below this the gather overhead beats the kernel-traffic saving).
-      block_t/block_k/block_b/block_a: pinned Pallas tile sizes for the
-        histogram (t=case, k=slot, b=bin) and split-gain (k=slot, a=attr)
-        kernels; None = shape-driven heuristic (repro.kernels.autotune).
+      block_t/block_k: pinned Pallas lane tiles (multiples of 128, or the
+        whole axis) for the histogram's case axis (t) and the slot axis (k)
+        of the histogram and split-gain kernels; None = shape-driven
+        heuristic (repro.kernels.autotune).
     """
 
     min_objs: float = 2.0
@@ -48,5 +49,3 @@ class GrowConfig:
     compact_min_bucket: int = 1024
     block_t: int | None = None
     block_k: int | None = None
-    block_b: int | None = None
-    block_a: int | None = None

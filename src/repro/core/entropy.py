@@ -17,7 +17,14 @@ The same functions are called by
   * the Pallas kernel oracle (``kernels/ref.py``),
 
 so that split decisions are bitwise comparable across engines (identical op
-order on identical histogram tensors).
+order on identical histogram tensors).  The Pallas split-gain kernel
+(``kernels/split_gain.py``) repeats the same op sequence per VMEM block, so
+on a TPU its scores equal these bit for bit for integer case weights.  Two
+rules keep XLA and Mosaic in step: ``log2`` is ``log(x) * LOG2_E`` (XLA
+rewrites ``jnp.log2``'s ``log(x) / log(2)`` differently from Mosaic, up to
+2 ulp apart on a v5e), and every sum of entropy terms runs left to right
+(:func:`ordered_sum`), never as a reduction whose association the compiler
+picks.  Sums of integer weights are exact in any order.
 
 All functions are pure jnp, dtype-stable (float32 by default), and batched:
 leading dimensions are arbitrary.
@@ -35,12 +42,32 @@ EPS_W = 1e-7
 EPS_GAIN = 1e-6
 
 NEG_INF = float("-inf")  # Python literal: safe to close over in Pallas kernels
+LOG2_E = 1.4426950408889634
+
+
+def log2(x: jnp.ndarray) -> jnp.ndarray:
+    """log2 as ``log(x) * LOG2_E``: one log and one multiply, which XLA and
+    Mosaic both evaluate to the same bits on a TPU."""
+    return jnp.log(x) * LOG2_E
+
+
+def ordered_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """Sum along ``axis`` strictly left to right: ``((x0 + x1) + x2) + ...``.
+
+    The association is fixed, so every engine (and the Pallas kernel, which
+    adds in the same order) rounds the same way.
+    """
+    x = jnp.moveaxis(x, axis, 0)
+    acc = x[0]
+    for i in range(1, x.shape[0]):
+        acc = acc + x[i]
+    return acc
 
 
 def _xlogx(p: jnp.ndarray) -> jnp.ndarray:
     """x * log2(x), continuously extended with 0 at x == 0."""
     safe = jnp.where(p > 0, p, 1.0)
-    return jnp.where(p > 0, p * (jnp.log2(safe)), 0.0)
+    return jnp.where(p > 0, p * log2(safe), 0.0)
 
 
 def info(counts: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
@@ -52,8 +79,8 @@ def info(counts: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     counts = counts.astype(jnp.float32)
     w = jnp.sum(counts, axis=axis)
     safe_w = jnp.where(w > EPS_W, w, 1.0)
-    s = jnp.sum(_xlogx(counts), axis=axis)
-    ent = jnp.log2(safe_w) - s / safe_w
+    s = ordered_sum(_xlogx(counts), axis=axis)
+    ent = log2(safe_w) - s / safe_w
     return jnp.where(w > EPS_W, jnp.maximum(ent, 0.0), 0.0)
 
 
@@ -65,7 +92,8 @@ def weighted_info(counts: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     """
     counts = counts.astype(jnp.float32)
     w = jnp.sum(counts, axis=axis)
-    return jnp.maximum(_xlogx(w) - jnp.sum(_xlogx(counts), axis=axis), 0.0)
+    return jnp.maximum(_xlogx(w) - ordered_sum(_xlogx(counts), axis=axis),
+                       0.0)
 
 
 def split_gain_from_children(
@@ -90,7 +118,7 @@ def split_gain_from_children(
     w_known = jnp.sum(parent, axis=-1)
     safe_w = jnp.where(w_known > EPS_W, w_known, 1.0)
     info_parent = weighted_info(parent)                       # W_k * info
-    info_children = jnp.sum(weighted_info(child_counts), axis=-1)
+    info_children = ordered_sum(weighted_info(child_counts), axis=-1)
     gain = (info_parent - info_children) / safe_w
     if total_w is not None:
         f = w_known / jnp.where(total_w > EPS_W, total_w, 1.0)

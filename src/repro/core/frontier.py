@@ -140,7 +140,7 @@ def _block_plan(prob: FrontierProblem, n_cases: int):
     from repro.kernels import autotune
     return autotune.plan_for_config(
         prob.cfg, n_cases=n_cases, n_bins=prob.n_bins_max,
-        n_classes=prob.n_classes, n_attrs=prob.n_attrs)
+        n_classes=prob.n_classes)
 
 
 def _histogram(x, y, w, slot, *, prob: FrontierProblem, impl: str):
@@ -153,12 +153,11 @@ def _histogram(x, y, w, slot, *, prob: FrontierProblem, impl: str):
                 x, y, w, slot, n_slots=k, n_bins=prob.n_bins_max,
                 n_classes=prob.n_classes,
                 min_bucket=prob.cfg.compact_min_bucket,
-                block_t=plan.block_t, block_k=plan.block_k,
-                block_b=plan.block_b)
+                block_t=plan.block_t, block_k=plan.block_k)
         return kernel_ops.frontier_histogram(
             x, y, w, slot, n_slots=k, n_bins=prob.n_bins_max,
             n_classes=prob.n_classes, block_t=plan.block_t,
-            block_k=plan.block_k, block_b=plan.block_b)
+            block_k=plan.block_k)
     return frontier_histogram_jnp(
         x, y, w, slot, n_slots=k, n_bins=prob.n_bins_max,
         n_classes=prob.n_classes)
@@ -168,10 +167,11 @@ def _gains(hist, total_w, attr_is_cont, n_bins, *, prob: FrontierProblem,
            impl: str):
     """splitAtt scoring: (K, A) score/bin planes from the (K, A, B, C) hist.
 
-    ``impl="pallas"`` runs the fused scan/entropy kernel — one HBM read of
-    the histogram, results bit-identical to the jnp path (the kernel body
-    calls the same :mod:`repro.core.entropy` functions per VMEM block, and
-    the (K, A) grid decomposition is exact for per-(node, attr) math).
+    ``impl="pallas"`` runs the fused scan/entropy kernel -- one HBM read of
+    the histogram.  It repeats the :mod:`repro.core.entropy` op order, so on
+    a TPU its scores equal the jnp path's bit for bit for integer case
+    weights.  Fractional weights and interpret mode on the CPU agree only to
+    f32 rounding (:mod:`repro.kernels.split_gain`).
     """
     cfg = prob.cfg
     if impl == "pallas":
@@ -179,8 +179,7 @@ def _gains(hist, total_w, attr_is_cont, n_bins, *, prob: FrontierProblem,
         plan = _block_plan(prob, prob.n_cases)
         return kernel_ops.split_gain(
             hist, total_w, attr_is_cont, n_bins, min_objs=cfg.min_objs,
-            criterion=cfg.criterion, block_k=plan.block_k,
-            block_a=plan.block_a)
+            criterion=cfg.criterion, block_k=plan.block_k)
     return entropy.gains_from_histogram(
         hist, total_w=total_w, attr_is_cont=attr_is_cont, n_bins=n_bins,
         min_objs=cfg.min_objs, criterion=cfg.criterion)

@@ -145,13 +145,13 @@ class Forest:
         )
 
     def node_table(self) -> jnp.ndarray:
-        """(T, M, NODE_COLS) int32 table for the Pallas traversal kernel."""
-        cols = jnp.stack(
+        """(T, NODE_COLS, M) int32 table for the Pallas traversal kernel."""
+        rows = jnp.stack(
             [self.node_attr, self.node_split_bin, self.node_child0,
              self.node_nchild, self.node_heavy, self.node_class],
-            axis=-1).astype(jnp.int32)
-        pad = tree_infer.NODE_COLS - cols.shape[-1]
-        return jnp.pad(cols, ((0, 0), (0, 0), (0, pad)))
+            axis=1).astype(jnp.int32)
+        pad = tree_infer.NODE_COLS - rows.shape[1]
+        return jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
 
 
 # ----------------------------------------------------------------- prediction
@@ -177,8 +177,7 @@ def _predict_vmap(forest: Forest, x_bins: jnp.ndarray,
 
 def predict_per_tree(forest: Forest, x_bins, attr_is_cont, *,
                      impl: str = "vmap", max_depth: int | None = None,
-                     block_n: int | None = None,
-                     interpret: bool | None = None) -> jnp.ndarray:
+                     block_n: int | None = None) -> jnp.ndarray:
     """(T, N) leaf classes, one row per packed tree."""
     if impl not in IMPLS:
         raise ValueError(f"unknown impl {impl!r} (one of {IMPLS})")
@@ -197,8 +196,7 @@ def predict_per_tree(forest: Forest, x_bins, attr_is_cont, *,
                              max_depth=max_depth)
     from repro.kernels import ops
     return ops.forest_predict(forest.node_table(), x_bins, attr_is_cont,
-                              max_depth=max_depth, block_n=block_n,
-                              interpret=interpret)
+                              max_depth=max_depth, block_n=block_n)
 
 
 def vote(per_tree: jnp.ndarray, tree_weight: jnp.ndarray, *,
@@ -215,16 +213,14 @@ def vote(per_tree: jnp.ndarray, tree_weight: jnp.ndarray, *,
 
 def predict(forest: Forest, x_bins, attr_is_cont, *, impl: str = "vmap",
             weighted: bool = True, max_depth: int | None = None,
-            block_n: int | None = None,
-            interpret: bool | None = None) -> jnp.ndarray:
+            block_n: int | None = None) -> jnp.ndarray:
     """(N,) ensemble prediction: per-tree descent + weighted majority vote.
 
     ``weighted=False`` ignores ``tree_weight`` (plain majority).  A 1-tree
     forest returns exactly that tree's predictions for every ``impl``.
     """
     per_tree = predict_per_tree(forest, x_bins, attr_is_cont, impl=impl,
-                                max_depth=max_depth, block_n=block_n,
-                                interpret=interpret)
+                                max_depth=max_depth, block_n=block_n)
     w = forest.tree_weight if weighted \
         else jnp.ones((forest.n_trees,), jnp.float32)
     return vote(per_tree, w, n_classes=forest.n_classes)

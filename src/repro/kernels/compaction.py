@@ -15,8 +15,8 @@ is N itself and skips the gather entirely (no regression on shallow
 supersteps where everything is live).
 
 Per-superstep cost: one ``nonzero`` scan + gather (O(N) but elementwise,
-~16 B/case) replaces O(N * ceil(K/block_k) * ceil(B/block_b)) kernel
-traffic — a win whenever the frontier is sparse.
+~16 B/case) replaces O(N * A * ceil(K/block_k)) kernel traffic — a win
+whenever the frontier is sparse.
 """
 
 from __future__ import annotations
@@ -59,8 +59,6 @@ def compact_frontier_histogram(
     min_bucket: int = 1024,
     block_t: int | None = None,
     block_k: int | None = None,
-    block_b: int | None = None,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """(K, A, B+1, C) weighted counts over the compacted live cases."""
     from repro.kernels import ops as kernel_ops
@@ -71,16 +69,10 @@ def compact_frontier_histogram(
     slot = jnp.asarray(slot)
     n = x.shape[0]
     kw = dict(n_slots=n_slots, n_bins=n_bins, n_classes=n_classes,
-              interpret=interpret)
-    if block_k is not None:
-        kw["block_k"] = block_k
-    if block_b is not None:
-        kw["block_b"] = block_b
+              block_t=block_t, block_k=block_k)
 
     def full(_):
-        return kernel_ops.frontier_histogram(
-            x, y, w, slot, **(kw if block_t is None
-                              else dict(kw, block_t=block_t)))
+        return kernel_ops.frontier_histogram(x, y, w, slot, **kw)
 
     sizes = bucket_sizes(n, min_bucket=min_bucket)
     if len(sizes) == 1:
@@ -96,9 +88,7 @@ def compact_frontier_histogram(
             xg = act.shard_active_cases(x[idx])
             sg = act.shard_active_cases(
                 jnp.where(live, slot[idx], -1).astype(jnp.int32))
-            bt = min(block_t or 512, max(8, size))
-            return kernel_ops.frontier_histogram(
-                xg, y[idx], w[idx], sg, **dict(kw, block_t=bt))
+            return kernel_ops.frontier_histogram(xg, y[idx], w[idx], sg, **kw)
         return run
 
     branches = [gathered(s) for s in sizes[:-1]] + [full]

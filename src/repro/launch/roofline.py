@@ -165,8 +165,6 @@ def model_flops_for(arch: str, shape_name: str) -> float:
 def analyze(compiled, *, arch: str, shape: str, mesh_desc: str,
             n_devices: int) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # older jax: one dict per program
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     coll, by_op = collective_bytes(compiled.as_text(), n_devices=n_devices)
     return Roofline(

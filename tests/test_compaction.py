@@ -73,30 +73,49 @@ def test_compact_gather_is_dense():
 
 def test_autotune_respects_budget_and_extents():
     for n, k, b, c, a in [(100, 4, 3, 2, 2), (1 << 20, 256, 128, 23, 41),
-                          (50_000, 64, 64, 7, 54)]:
+                          (50_000, 64, 64, 7, 54),
+                          (1 << 20, 256, 256, 23, 41)]:
         p = autotune.plan_blocks(n_cases=n, n_slots=k, n_bins=b,
-                                 n_classes=c, n_attrs=a)
-        # the one-hot expansion + out window must fit the budget
-        hist_bytes = 4 * (p.block_t * p.block_k * p.block_b
-                          + p.block_k * p.block_b * c)
-        gain_bytes = 16 * p.block_k * p.block_a * b * c
+                                 n_classes=c)
+        # padded, double-buffered blocks of both kernels fit the budget
+        hist_bytes = autotune.hist_vmem_bytes(
+            block_t=p.block_t, block_k=p.block_k, n_bins=b, n_classes=c)
+        gain_bytes = autotune.gain_vmem_bytes(block_k=p.block_k, n_bins=b,
+                                              n_classes=c)
         assert hist_bytes <= autotune.VMEM_BUDGET, (n, k, b, c, a)
         assert gain_bytes <= autotune.VMEM_BUDGET, (n, k, b, c, a)
-        for v in (p.block_t, p.block_k, p.block_b, p.block_a):
-            assert v >= 1 and v & (v - 1) == 0          # power of two
+        # lane tiles: 128-aligned or the whole axis
+        assert p.block_t == n or p.block_t % 128 == 0
+        assert p.block_k == k or p.block_k % 128 == 0
+
+
+def test_autotune_counts_padded_double_buffered_vmem():
+    # a (1, 1) int32 block still takes one (8, 128) tile
+    assert autotune.tile_bytes(1, 1) == 8 * 128 * 4
+    assert autotune.tile_bytes(3, 9, 130) == 3 * 16 * 256 * 4
+    # an (8, M) node table at M = 2**15 is 1 MiB per buffer
+    m = 1 << 15
+    small = autotune.infer_vmem_bytes(block_n=128, capacity=128, chunk=128,
+                                      n_attrs=9)
+    big = autotune.infer_vmem_bytes(block_n=128, capacity=m, chunk=128,
+                                    n_attrs=9)
+    assert big - small == 2 * 8 * (m - 128) * 4
+    with pytest.raises(autotune.VmemError, match="MiB"):
+        ops.forest_predict(jnp.zeros((1, 8, 1 << 22), jnp.int32),
+                           jnp.zeros((4, 9), jnp.int32),
+                           jnp.zeros((9,), bool), max_depth=1)
 
 
 def test_autotune_overrides_win():
     p = autotune.plan_blocks(n_cases=10_000, n_slots=64, n_bins=32,
-                             n_classes=4, n_attrs=9,
-                             block_t=128, block_k=2, block_b=16, block_a=4)
-    assert (p.block_t, p.block_k, p.block_b, p.block_a) == (128, 2, 16, 4)
+                             n_classes=4,
+                             block_t=128, block_k=64)
+    assert (p.block_t, p.block_k) == (128, 64)
 
 
 def test_plan_for_config_reads_grow_config():
     from repro.core.config import GrowConfig
-    cfg = GrowConfig(frontier_slots=32, block_k=4)
-    p = autotune.plan_for_config(cfg, n_cases=5000, n_bins=16, n_classes=3,
-                                 n_attrs=7)
-    assert p.block_k == 4
-    assert p.block_b == 32          # padded bin axis (16+1 -> 32) fits whole
+    cfg = GrowConfig(frontier_slots=384, block_k=128)
+    p = autotune.plan_for_config(cfg, n_cases=5000, n_bins=16, n_classes=3)
+    assert p.block_k == 128
+    assert p.block_t == 1024        # largest case tile that fits

@@ -18,11 +18,11 @@ SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
 
     from repro.configs import base as cfgbase
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_auto_mesh
     from repro.launch.specs import make_cell, lower_cell
     from repro.launch import roofline as rl
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
 
     # shrink shapes so the tiny mesh compiles in seconds
     cfgbase.SHAPES = {

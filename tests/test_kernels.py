@@ -31,21 +31,32 @@ def test_histogram_matches_ref(n, a, b, c, k):
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-5)
 
 
-@pytest.mark.parametrize("block_t,block_k,block_b", [
-    (8, 1, 2), (64, 4, 16), (512, 8, 128)])
-def test_histogram_block_shapes(block_t, block_k, block_b):
+@pytest.mark.parametrize("block_t,block_k,k", [
+    (128, 128, 300),    # several case tiles and slot blocks
+    (256, 256, 6),      # tiles covering whole axes
+    (128, 256, 260)])   # slot axis padded up to the block
+def test_histogram_block_shapes(block_t, block_k, k):
     rng = np.random.default_rng(3)
-    n, a, b, c, k = 150, 2, 9, 3, 6
+    n, a, b, c = 150, 2, 9, 3
     x = rng.integers(-1, b, (n, a)).astype(np.int32)
     y = rng.integers(0, c, n).astype(np.int32)
     w = np.ones(n, np.float32)
     slot = rng.integers(-1, k, n).astype(np.int32)
     got = np.asarray(ops.frontier_histogram(
         x, y, w, slot, n_slots=k, n_bins=b, n_classes=c,
-        block_t=block_t, block_k=block_k, block_b=block_b))
+        block_t=block_t, block_k=block_k))
     want = np.asarray(ref.frontier_histogram_ref(
         x, y, w, slot, n_slots=k, n_bins=b, n_classes=c))
     np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+def test_histogram_rejects_unaligned_lane_tiles():
+    """Tiles Mosaic would refuse are refused in interpret mode too."""
+    x = np.zeros((300, 2), np.int32)
+    z = np.zeros(300, np.int32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        ops.frontier_histogram(x, z, z.astype(np.float32), z, n_slots=4,
+                               n_bins=3, n_classes=2, block_t=100)
 
 
 def test_histogram_conservation():
