@@ -1,0 +1,9 @@
+"""Device ms per tree in the fused build's ``frontier.compact`` scope,
+scopes nested in it included: compaction: the live-case count, the
+``nonzero`` over the cases and the gathers (``bench/scopes.py``)."""
+
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.ms_per_tree(ctx, "frontier.compact")

@@ -19,6 +19,12 @@ Everything is fixed-shape and jit-able; the full build is a
 host-side through the supervised threaded farm — :func:`build_farm` — which
 tolerates worker crashes/hangs/deaths (:mod:`repro.core.farm_build`) and
 stays elementwise-equal to both this engine and the sequential oracle.
+The build names its phases for the profiler: every operation of the fused
+program runs under one of the ``jax.named_scope`` names ``frontier.init``,
+``frontier.split_pre`` (``frontier.select`` nested), ``frontier.split_att``
+(``frontier.compact`` nested) and ``frontier.split_post`` (``frontier.route``
+nested), and :func:`build_scopes` maps the compiled program's instructions
+back to them.  These names are an interface: the benchmark reads them.
 The splitAtt hot-spot is pluggable:
 ``impl="jnp"`` scores gains from a segment-sum histogram (reference);
 ``impl="pallas"`` runs the whole phase on the kernels in
@@ -32,7 +38,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any
 
 import jax
@@ -43,8 +48,37 @@ from repro.core import cost_models, entropy
 from repro.core.binning import BinnedDataset
 from repro.core.config import GrowConfig
 from repro.core.tree import Tree
+from repro.kernels import compaction
+from repro.obs import metrics as obs_metrics
+from repro.obs import trace
 
 EPS_W = entropy.EPS_W
+
+# A total of cases over supersteps passes 2**31 at the paper's sizes (10M
+# cases x ~700 supersteps), so it is carried as (high, low) int32 words with
+# LOW_BITS in the low word: exact to 2**61 without 64-bit mode.
+LOW_BITS = 30
+_LOW_MASK = (1 << LOW_BITS) - 1
+
+
+def _wide_add(words: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """(high, low) words of a total, plus ``v`` in [0, 2**31)."""
+    low = words[1] + (v & _LOW_MASK)
+    return jnp.stack([words[0] + (v >> LOW_BITS) + (low >> LOW_BITS),
+                      low & _LOW_MASK])
+
+
+class _WideTotal:
+    """A wide total's device words; ``float()`` reads them as one number."""
+
+    __slots__ = ("words",)
+
+    def __init__(self, words: jnp.ndarray):
+        self.words = words
+
+    def __float__(self) -> float:
+        high, low = np.asarray(self.words).tolist()
+        return float((high << LOW_BITS) | low)
 
 
 @jax.tree_util.register_dataclass
@@ -56,6 +90,11 @@ class GrowState:
     case_node: jnp.ndarray   # int32 (N,): current node of each case
     n_nodes: jnp.ndarray     # int32 scalar
     overflow: jnp.ndarray    # bool scalar — capacity forced early leaves
+    # totals over the supersteps run so far (build() publishes them)
+    supersteps: jnp.ndarray  # int32 scalar
+    open_nodes: jnp.ndarray  # int32 scalar: open nodes processed
+    live_steps: jnp.ndarray  # int32 (2,) wide: Σ cases in an open node
+    hist_steps: jnp.ndarray  # int32 (2,) wide: Σ cases the histogram got
 
     STATUS_EMPTY = 0
     STATUS_OPEN = 1
@@ -85,6 +124,11 @@ class FrontierProblem:
 
 def init_state(prob: FrontierProblem, y: jnp.ndarray, w: jnp.ndarray,
                attr_mask: jnp.ndarray | None = None) -> GrowState:
+    with jax.named_scope("frontier.init"):
+        return _init_state(prob, y, w, attr_mask)
+
+
+def _init_state(prob, y, w, attr_mask) -> GrowState:
     cfg = prob.cfg
     tree = Tree.empty(cfg.max_nodes, prob.n_classes)
     root_freq = jax.ops.segment_sum(w.astype(jnp.float32), y,
@@ -103,6 +147,10 @@ def init_state(prob: FrontierProblem, y: jnp.ndarray, w: jnp.ndarray,
         case_node=jnp.zeros((prob.n_cases,), jnp.int32),
         n_nodes=jnp.int32(1),
         overflow=jnp.bool_(False),
+        supersteps=jnp.int32(0),
+        open_nodes=jnp.int32(0),
+        live_steps=jnp.zeros((2,), jnp.int32),
+        hist_steps=jnp.zeros((2,), jnp.int32),
     )
 
 
@@ -143,24 +191,33 @@ def _block_plan(prob: FrontierProblem, n_cases: int):
         n_classes=prob.n_classes)
 
 
-def _histogram(x, y, w, slot, *, prob: FrontierProblem, impl: str):
+def _histogram(x, y, w, slot, n_live, *, prob: FrontierProblem, impl: str):
+    """(K, A, B+1, C) histogram, and how many cases it was computed over:
+    the compaction bucket that holds the ``n_live`` live cases, else N."""
     k = prob.cfg.frontier_slots
+    n = prob.n_cases
     if impl == "pallas":
         from repro.kernels import ops as kernel_ops
-        plan = _block_plan(prob, prob.n_cases)
+        plan = _block_plan(prob, n)
         if prob.cfg.compact:
-            return kernel_ops.frontier_histogram_compact(
+            sizes = compaction.bucket_sizes(
+                n, min_bucket=prob.cfg.compact_min_bucket)
+            hist = kernel_ops.frontier_histogram_compact(
                 x, y, w, slot, n_slots=k, n_bins=prob.n_bins_max,
                 n_classes=prob.n_classes,
                 min_bucket=prob.cfg.compact_min_bucket,
-                block_t=plan.block_t, block_k=plan.block_k)
+                block_t=plan.block_t, block_k=plan.block_k,
+                scope="frontier.compact")
+            given = jnp.asarray(sizes, jnp.int32)[
+                compaction.pick_bucket(n_live, sizes)]
+            return hist, given
         return kernel_ops.frontier_histogram(
             x, y, w, slot, n_slots=k, n_bins=prob.n_bins_max,
             n_classes=prob.n_classes, block_t=plan.block_t,
-            block_k=plan.block_k)
+            block_k=plan.block_k), jnp.int32(n)
     return frontier_histogram_jnp(
         x, y, w, slot, n_slots=k, n_bins=prob.n_bins_max,
-        n_classes=prob.n_classes)
+        n_classes=prob.n_classes), jnp.int32(n)
 
 
 def _gains(hist, total_w, attr_is_cont, n_bins, *, prob: FrontierProblem,
@@ -187,9 +244,8 @@ def _gains(hist, total_w, attr_is_cont, n_bins, *, prob: FrontierProblem,
 
 # --------------------------------------------------------------------------
 # One superstep = splitPre + splitAtt + splitPost over K open nodes.
-# The phases are separate jit-able functions so the observability path
-# (build(collect_stats=True, tracer=...)) can time each one; ``superstep``
-# composes them and is what the fused whole-build while_loop traces.
+# ``superstep`` composes the phases, each under its ``frontier.*`` scope, and
+# is what the fused whole-build while_loop traces.
 # --------------------------------------------------------------------------
 
 def split_pre(state: GrowState, *, prob: FrontierProblem
@@ -201,13 +257,13 @@ def split_pre(state: GrowState, *, prob: FrontierProblem
     tree = state.tree
 
     # ---- select up to K open nodes, FIFO by id (= breadth-first) ----------
-    ids = jnp.nonzero(state.status == GrowState.STATUS_OPEN,
-                      size=k, fill_value=m)[0].astype(jnp.int32)
-    valid = ids < m
-    ids_safe = jnp.minimum(ids, m - 1)
-
-    node_to_slot = jnp.full((m + 1,), -1, jnp.int32).at[ids].set(
-        jnp.arange(k, dtype=jnp.int32), mode="drop")
+    with jax.named_scope("frontier.select"):
+        ids = jnp.nonzero(state.status == GrowState.STATUS_OPEN,
+                          size=k, fill_value=m)[0].astype(jnp.int32)
+        valid = ids < m
+        ids_safe = jnp.minimum(ids, m - 1)
+        node_to_slot = jnp.full((m + 1,), -1, jnp.int32).at[ids].set(
+            jnp.arange(k, dtype=jnp.int32), mode="drop")
     slot = node_to_slot[state.case_node]                      # (N,)
 
     # ---- stop tests on stored frequencies ----------------------------------
@@ -229,8 +285,11 @@ def split_att(state: GrowState, pre: dict,
     """The hot phase: fused histogram + gain over (node, attribute)."""
     b_dim = prob.n_bins_max
     from repro.sharding.act import shard_frontier_hist
-    hist_u = shard_frontier_hist(
-        _histogram(x, y, w, pre["slot"], prob=prob, impl=impl))  # (K,A,B+1,C)
+    with jax.named_scope("frontier.compact"):
+        n_live = jnp.sum((pre["slot"] >= 0).astype(jnp.int32))
+    hist_u, n_hist = _histogram(x, y, w, pre["slot"], n_live, prob=prob,
+                                impl=impl)
+    hist_u = shard_frontier_hist(hist_u)                      # (K,A,B+1,C)
     hist = hist_u[:, :, :b_dim, :]
     unknown = hist_u[:, :, b_dim, :]                          # (K, A, C)
     score, split_bin = _gains(
@@ -240,7 +299,8 @@ def split_att(state: GrowState, pre: dict,
     best_attr, best_score, has_split = entropy.pick_best_attribute(
         score, active_k)
     return dict(hist=hist, unknown=unknown, split_bin=split_bin,
-                active_k=active_k, best_attr=best_attr, has_split=has_split)
+                active_k=active_k, best_attr=best_attr, has_split=has_split,
+                n_live=n_live, n_hist=n_hist)
 
 
 def split_post(state: GrowState, pre: dict, att: dict,
@@ -346,33 +406,40 @@ def split_post(state: GrowState, pre: dict, att: dict,
                          (k, h_dim, a_dim)).reshape(-1, a_dim), mode="drop")
 
     # ---- route cases to their child (the feedback edge) --------------------
-    part = slot >= 0
-    slot_safe = jnp.maximum(slot, 0)
-    a_case = best_attr[slot_safe]
-    # Row-local select of x[i, a_case[i]].  A take_along_axis here makes the
-    # SPMD partitioner materialise replicated (N, 1, 2) gather indices plus
-    # an all-reduce of the result — 120 MB/superstep of pure routing traffic
-    # (measured).  The one-hot contraction is elementwise row-local: zero
-    # collectives, A x s32 reads (A = 9).
-    onehot_a = (jnp.arange(a_dim, dtype=jnp.int32)[None, :]
-                == a_case[:, None])
-    b_case = jnp.sum(jnp.where(onehot_a, x, 0), axis=1)
-    j_cont = jnp.where(b_case <= sb[slot_safe], 0, 1)
-    j_case = jnp.where(is_cont[slot_safe], j_cont, b_case)
-    j_case = jnp.where(b_case < 0, heaviest[slot_safe], j_case)
-    new_node = child0[slot_safe] + j_case
-    case_node = jnp.where(part & internal[slot_safe], new_node,
-                          state.case_node).astype(jnp.int32)
+    with jax.named_scope("frontier.route"):
+        part = slot >= 0
+        slot_safe = jnp.maximum(slot, 0)
+        a_case = best_attr[slot_safe]
+        # Row-local select of x[i, a_case[i]].  A take_along_axis here makes
+        # the SPMD partitioner materialise replicated (N, 1, 2) gather
+        # indices plus an all-reduce of the result — 120 MB/superstep of pure
+        # routing traffic (measured).  The one-hot contraction is elementwise
+        # row-local: zero collectives, A x s32 reads (A = 9).
+        onehot_a = (jnp.arange(a_dim, dtype=jnp.int32)[None, :]
+                    == a_case[:, None])
+        b_case = jnp.sum(jnp.where(onehot_a, x, 0), axis=1)
+        j_cont = jnp.where(b_case <= sb[slot_safe], 0, 1)
+        j_case = jnp.where(is_cont[slot_safe], j_cont, b_case)
+        j_case = jnp.where(b_case < 0, heaviest[slot_safe], j_case)
+        new_node = child0[slot_safe] + j_case
+        case_node = jnp.where(part & internal[slot_safe], new_node,
+                              state.case_node).astype(jnp.int32)
 
+    n_processed = jnp.sum(valid.astype(jnp.int32))
     new_state = GrowState(
         tree=dataclasses.replace(tree, n_nodes=state.n_nodes + total_children),
         status=status, active=active, case_node=case_node,
         n_nodes=state.n_nodes + total_children,
         overflow=state.overflow | overflow,
+        supersteps=state.supersteps + 1,
+        open_nodes=state.open_nodes + n_processed,
+        live_steps=_wide_add(state.live_steps, att["n_live"]),
+        hist_steps=_wide_add(state.hist_steps, att["n_hist"]),
     )
     stats = dict(
-        n_processed=jnp.sum(valid.astype(jnp.int32)),
-        n_active=jnp.sum((slot >= 0).astype(jnp.int32)),
+        n_processed=n_processed,
+        n_active=att["n_live"],
+        n_hist=att["n_hist"],
         n_internal=jnp.sum(internal.astype(jnp.int32)),
         n_children=total_children,
         max_r=jnp.max(jnp.where(valid, total_w, 0.0)),
@@ -391,10 +458,14 @@ def superstep(
     *, prob: FrontierProblem, impl: str = "jnp",
 ) -> tuple[GrowState, dict[str, jnp.ndarray]]:
     """One fused superstep: splitPre → splitAtt → splitPost."""
-    pre = split_pre(state, prob=prob)
-    att = split_att(state, pre, x, y, w, attr_is_cont, n_bins,
-                    prob=prob, impl=impl)
-    return split_post(state, pre, att, x, attr_is_cont, n_bins, prob=prob)
+    with jax.named_scope("frontier.split_pre"):
+        pre = split_pre(state, prob=prob)
+    with jax.named_scope("frontier.split_att"):
+        att = split_att(state, pre, x, y, w, attr_is_cont, n_bins,
+                        prob=prob, impl=impl)
+    with jax.named_scope("frontier.split_post"):
+        return split_post(state, pre, att, x, attr_is_cont, n_bins,
+                          prob=prob)
 
 
 # --------------------------------------------------------------------------
@@ -415,7 +486,9 @@ def _build_jit(x, y, w, attr_mask, attr_is_cont, n_bins, *,
     step = _superstep_fn(prob, impl)
 
     def cond(state):
-        return jnp.any(state.status == GrowState.STATUS_OPEN)
+        with jax.named_scope("frontier.split_pre"), \
+                jax.named_scope("frontier.select"):
+            return jnp.any(state.status == GrowState.STATUS_OPEN)
 
     def body(state):
         new_state, _ = step(state, x, y, w, attr_is_cont, n_bins)
@@ -424,25 +497,79 @@ def _build_jit(x, y, w, attr_mask, attr_is_cont, n_bins, *,
     return jax.lax.while_loop(cond, body, state)
 
 
+# (prob, impl, argument shapes and dtypes) of every _build_jit program that
+# build() has dispatched in this process: what build_scopes() compiles.
+_DISPATCHED: dict[tuple, None] = {}
+
+
+def build_scopes() -> dict[str, trace.HloScope]:
+    """``{HLO instruction name: (innermost frontier.* scope, rule)}`` over
+    every whole-build program :func:`build` has dispatched in this process
+    (the rule that found the scope: :func:`repro.obs.trace.hlo_scopes`).
+
+    A device trace names each operation by its HLO instruction
+    (``fusion.241``); this map gives it to its phase.  The scopes are
+    ``frontier.init``, ``frontier.split_pre`` > ``frontier.select``,
+    ``frontier.split_att`` > ``frontier.compact`` and ``frontier.split_post``
+    > ``frontier.route``; what the compiler adds outside the loop to fill
+    its initial state counts as ``frontier.init``, and the loop's
+    scaffolding (while, tuples, copies and prefetches the compiler adds
+    inside it) may carry none.  Made on request and never on
+    a build's path: each program is lowered and compiled again from its
+    recorded shapes, which the compile cache turns into a load, and XLA
+    names a program's instructions the same way on every compile.  Where two
+    programs use one instruction name, the later program's scope wins.
+    """
+    out: dict[str, trace.HloScope] = {}
+    for prob, impl, specs in list(_DISPATCHED):
+        args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in specs]
+        compiled = _build_jit.lower(*args, prob=prob, impl=impl).compile()
+        out.update(trace.hlo_scopes(compiled.as_text(), "frontier.",
+                                    entry_scope="frontier.init"))
+    return out
+
+
+def _publish(state: GrowState, prob: FrontierProblem,
+             reg: obs_metrics.Registry) -> None:
+    """The one writer of the ``frontier_*`` gauges: the last build's totals,
+    as device values that are read only when the registry is read."""
+    reg.gauge("frontier_supersteps",
+              "supersteps of the last build").set(state.supersteps)
+    reg.gauge("frontier_open_nodes",
+              "open nodes the last build processed").set(state.open_nodes)
+    reg.gauge("frontier_live_case_steps",
+              "sum over the last build's supersteps of the cases in an "
+              "open node").set(_WideTotal(state.live_steps))
+    reg.gauge("frontier_hist_case_steps",
+              "sum over the last build's supersteps of the cases the "
+              "histogram was given").set(_WideTotal(state.hist_steps))
+    reg.gauge("frontier_cases",
+              "training cases of the last build").set(prob.n_cases)
+    reg.gauge("frontier_slots",
+              "frontier slots of the last build").set(prob.cfg.frontier_slots)
+
+
 def build(ds: BinnedDataset, cfg: GrowConfig = GrowConfig(), *,
           impl: str = "jnp", collect_stats: bool = False,
-          tracer: Any = None, metrics: Any = None,
+          metrics: obs_metrics.Registry | None = None,
           attr_mask: Any = None, case_w: Any = None,
           ) -> Tree | tuple[Tree, list[dict[str, Any]]]:
     """Grow a C4.5 tree with the SPMD frontier engine.
 
-    With ``collect_stats=True`` the superstep loop runs host-side and returns
-    per-superstep scheduling statistics (NP vs NAP decisions per the
-    configured cost model — the data behind paper Fig. 15); the per-step
-    ``n_active``/``nap_nodes``/... values also flow into the metrics
-    registry (``metrics``, default the process-wide one).
+    The build is one jitted ``while_loop`` over supersteps.  It carries its
+    totals (supersteps, open nodes processed, cases in an open node and
+    cases the histogram was given, summed over supersteps) and writes them,
+    N and K to the ``frontier_*`` gauges of ``metrics`` (default the
+    process-wide :data:`repro.obs.metrics.REGISTRY`) without waiting for
+    the device.  Its host work runs under the profiler annotations
+    ``frontier.build`` and ``frontier.to_device`` (the copy of the training
+    set that every call makes).
 
-    With an *enabled* ``tracer`` (:class:`repro.obs.trace.Tracer`) the loop
-    additionally runs the three phases as separately jitted, synchronously
-    timed steps, so the exported trace shows real splitPre / splitAtt /
-    splitPost wall time per superstep.  With tracing disabled nothing
-    changes: the fused single-jit superstep (or the whole-build
-    ``while_loop``) runs exactly as before.
+    With ``collect_stats=True`` the superstep loop runs host-side instead
+    and also returns one row of scheduling statistics per superstep (NP vs
+    NAP decisions per the configured cost model — the data behind paper
+    Fig. 15; ``n_active`` and ``n_hist`` are the live cases and the cases
+    the histogram was given).
 
     ``attr_mask`` (bool (A,)) restricts the split search to a subset of
     attributes; ``case_w`` (f32 (N,)) overrides the per-case weights — the
@@ -454,73 +581,32 @@ def build(ds: BinnedDataset, cfg: GrowConfig = GrowConfig(), *,
         raise ValueError("frontier engine routes unknowns to the heaviest "
                          "child; use the c45 oracle for fractional semantics")
     prob = FrontierProblem.from_dataset(ds, cfg)
-    x = jnp.asarray(ds.x)
-    y = jnp.asarray(ds.y)
-    w = jnp.asarray(ds.w if case_w is None else case_w, jnp.float32)
-    mask = (jnp.ones((ds.n_attrs,), bool) if attr_mask is None
-            else jnp.asarray(attr_mask, bool))
-    cont = jnp.asarray(ds.attr_is_cont)
-    nb = jnp.asarray(ds.n_bins, jnp.int32)
-    traced = tracer is not None and tracer.enabled
-
-    if not collect_stats and not traced:
-        state = _build_jit(x, y, w, mask, cont, nb, prob=prob, impl=impl)
-        return dataclasses.replace(state.tree, n_nodes=state.n_nodes)
-
-    from repro.obs import metrics as obs_metrics
-    reg = metrics if metrics is not None else obs_metrics.REGISTRY
-    m_steps = reg.counter("frontier_supersteps_total")
-    m_active = reg.gauge("frontier_active_cases")
-    m_open = reg.gauge("frontier_open_nodes")
-    m_nap = reg.counter("frontier_nap_nodes_total")
-    m_children = reg.counter("frontier_children_total")
-    m_phase = reg.histogram("frontier_phase_seconds",
-                            "per-phase superstep wall time, phase= label")
-
-    if traced:
-        pre_j = jax.jit(functools.partial(split_pre, prob=prob))
-        att_j = jax.jit(functools.partial(split_att, prob=prob, impl=impl))
-        post_j = jax.jit(functools.partial(split_post, prob=prob))
-
-        def timed_phase(name, fn, *args):
-            t0 = time.perf_counter()
-            with tracer.span(name):
-                out = jax.block_until_ready(fn(*args))
-            m_phase.observe(time.perf_counter() - t0, phase=name)
-            return out
-
-        def step_fn(state, step_i):
-            with tracer.span("superstep", step=step_i):
-                pre = timed_phase("splitPre", pre_j, state)
-                att = timed_phase("splitAtt", att_j, state, pre,
-                                  x, y, w, cont, nb)
-                return timed_phase("splitPost", post_j, state, pre, att,
-                                   x, cont, nb)
-    else:
-        fused = jax.jit(_superstep_fn(prob, impl))
-
-        def step_fn(state, step_i):
-            return fused(state, x, y, w, cont, nb)
-
-    state = init_state(prob, y, w, mask)
-    out: list[dict[str, Any]] = []
-    step_i = 0
-    while bool(jnp.any(state.status == GrowState.STATUS_OPEN)):
-        state, stats = step_fn(state, step_i)
-        row = {k: np.asarray(v).item() for k, v in stats.items()}
-        out.append(row)
-        m_steps.inc()
-        m_active.set(row["n_active"])
-        m_open.set(row["n_processed"])
-        m_nap.inc(row["nap_nodes"])
-        m_children.inc(row["n_children"])
-        if traced:
-            tracer.counter("frontier.n_active", value=row["n_active"])
-        step_i += 1
+    with trace.annotation("frontier.build"):
+        with trace.annotation("frontier.to_device"):
+            x = jnp.asarray(ds.x)
+            y = jnp.asarray(ds.y)
+            w = jnp.asarray(ds.w if case_w is None else case_w, jnp.float32)
+            mask = (jnp.ones((ds.n_attrs,), bool) if attr_mask is None
+                    else jnp.asarray(attr_mask, bool))
+            cont = jnp.asarray(ds.attr_is_cont)
+            nb = jnp.asarray(ds.n_bins, jnp.int32)
+        args = (x, y, w, mask, cont, nb)
+        rows: list[dict[str, Any]] = []
+        if collect_stats:
+            fused = jax.jit(_superstep_fn(prob, impl))
+            state = init_state(prob, y, w, mask)
+            while bool(jnp.any(state.status == GrowState.STATUS_OPEN)):
+                state, stats = fused(state, x, y, w, cont, nb)
+                rows.append({k: np.asarray(v).item()
+                             for k, v in stats.items()})
+        else:
+            _DISPATCHED.setdefault(
+                (prob, impl, tuple((a.shape, a.dtype) for a in args)))
+            state = _build_jit(*args, prob=prob, impl=impl)
+        _publish(state, prob,
+                 obs_metrics.REGISTRY if metrics is None else metrics)
     tree = dataclasses.replace(state.tree, n_nodes=state.n_nodes)
-    if not collect_stats:
-        return tree
-    return tree, out
+    return (tree, rows) if collect_stats else tree
 
 
 def build_farm(ds: BinnedDataset, cfg: GrowConfig = GrowConfig(), **kw):

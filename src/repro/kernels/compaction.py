@@ -47,6 +47,14 @@ def bucket_sizes(n_cases: int, *, min_bucket: int = 1024) -> tuple[int, ...]:
     return tuple(sizes)
 
 
+def pick_bucket(n_active: jnp.ndarray, sizes: tuple[int, ...]) -> jnp.ndarray:
+    """Index into ``sizes`` of the smallest bucket that holds ``n_active``
+    live cases: the gather branch the histogram takes, and so the number of
+    cases its kernel is given (``sizes[index]``)."""
+    return jnp.searchsorted(jnp.asarray(sizes, jnp.int32), n_active,
+                            side="left").astype(jnp.int32)
+
+
 def compact_frontier_histogram(
     x: jnp.ndarray,          # int32 (N, A) bins; -1 = unknown
     y: jnp.ndarray,          # int32 (N,) class labels
@@ -59,8 +67,14 @@ def compact_frontier_histogram(
     min_bucket: int = 1024,
     block_t: int | None = None,
     block_k: int | None = None,
+    scope: str = "compaction",
 ) -> jnp.ndarray:
-    """(K, A, B+1, C) weighted counts over the compacted live cases."""
+    """(K, A, B+1, C) weighted counts over the compacted live cases.
+
+    The live-case count, the ``nonzero`` and the gathers run under
+    ``jax.named_scope(scope)``, a name the caller may give them in its own
+    terms; the kernel keeps its own name.
+    """
     from repro.kernels import ops as kernel_ops
 
     x = jnp.asarray(x)
@@ -78,20 +92,21 @@ def compact_frontier_histogram(
     if len(sizes) == 1:
         return full(None)
 
-    part = slot >= 0
-    n_active = jnp.sum(part.astype(jnp.int32))
+    with jax.named_scope(scope):
+        part = slot >= 0
+        n_active = jnp.sum(part.astype(jnp.int32))
 
     def gathered(size: int):
         def run(_):
-            idx = jnp.nonzero(part, size=size, fill_value=0)[0]
-            live = jnp.arange(size, dtype=jnp.int32) < n_active
-            xg = act.shard_active_cases(x[idx])
-            sg = act.shard_active_cases(
-                jnp.where(live, slot[idx], -1).astype(jnp.int32))
-            return kernel_ops.frontier_histogram(xg, y[idx], w[idx], sg, **kw)
+            with jax.named_scope(scope):
+                idx = jnp.nonzero(part, size=size, fill_value=0)[0]
+                live = jnp.arange(size, dtype=jnp.int32) < n_active
+                xg = act.shard_active_cases(x[idx])
+                sg = act.shard_active_cases(
+                    jnp.where(live, slot[idx], -1).astype(jnp.int32))
+                yg, wg = y[idx], w[idx]
+            return kernel_ops.frontier_histogram(xg, yg, wg, sg, **kw)
         return run
 
     branches = [gathered(s) for s in sizes[:-1]] + [full]
-    sel = jnp.searchsorted(jnp.asarray(sizes, jnp.int32), n_active,
-                           side="left").astype(jnp.int32)
-    return jax.lax.switch(sel, branches, None)
+    return jax.lax.switch(pick_bucket(n_active, sizes), branches, None)

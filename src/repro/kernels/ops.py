@@ -44,17 +44,19 @@ def frontier_histogram(x, y, w, slot, *, n_slots: int, n_bins: int,
 def frontier_histogram_compact(x, y, w, slot, *, n_slots: int, n_bins: int,
                                n_classes: int, min_bucket: int = 1024,
                                block_t: int | None = None,
-                               block_k: int | None = None) -> jnp.ndarray:
+                               block_k: int | None = None,
+                               scope: str = "compaction") -> jnp.ndarray:
     """Histogram kernel over the compacted live cases (bucketed gather).
 
     Same contract as :func:`frontier_histogram`; the case-tile grid scales
     with the open frontier's live-case count instead of N (see
-    :mod:`repro.kernels.compaction`).
+    :mod:`repro.kernels.compaction`).  ``scope`` names the gather's
+    operations for the profiler.
     """
     from repro.kernels import compaction
     return compaction.compact_frontier_histogram(
         x, y, w, slot, n_slots=n_slots, n_bins=n_bins, n_classes=n_classes,
-        min_bucket=min_bucket, block_t=block_t, block_k=block_k)
+        min_bucket=min_bucket, block_t=block_t, block_k=block_k, scope=scope)
 
 
 def forest_predict(node_tab, x_bins, attr_is_cont, *, max_depth: int,

@@ -14,12 +14,13 @@ repo's three runtimes expose that data:
                               queued-weight timelines, latency histograms).
 
 Instrumented producers: the supervised farm (:mod:`repro.core.farm`), the
-SPMD frontier engine (:func:`repro.core.frontier.build` with
-``collect_stats``/``tracer``), the serving engine
+SPMD frontier engine (:func:`repro.core.frontier.build`: named phases in
+its fused program, per-superstep totals in the registry, profiler
+annotations around its host work), the serving engine
 (:mod:`repro.serve.engine`) and the heartbeat plane
-(:mod:`repro.train.elastic`).  Everything is zero-cost when tracing is
-disabled: the default :data:`repro.obs.trace.NULL` tracer short-circuits
-every call.
+(:mod:`repro.train.elastic`).  Enabled spans also land in a JAX profiler
+trace.  Everything is zero-cost when tracing is disabled: the default
+:data:`repro.obs.trace.NULL` tracer short-circuits every call.
 """
 
 from repro.obs.metrics import REGISTRY, Registry  # noqa: F401

@@ -5,8 +5,8 @@ Prometheus client model):
 
   * :class:`Counter`   — monotonically increasing totals
     (``farm_events_total{event="retry"}``);
-  * :class:`Gauge`     — last-written values
-    (``frontier_active_cases``, ``heartbeat_hosts_alive``);
+  * :class:`Gauge`     — last-written values, device scalars read lazily
+    (``frontier_supersteps``, ``heartbeat_hosts_alive``);
   * :class:`Histogram` — bucketed distributions with sum/count
     (``engine_queue_wait_ticks``).
 
@@ -71,25 +71,30 @@ class Counter(Metric):
 
 
 class Gauge(Metric):
+    """Last-written values.  ``set`` keeps what it is given, a device
+    scalar included, and ``float()`` turns it into a number only when the
+    gauge is read, so a writer on a device's hot path never waits for it."""
+
     kind = "gauge"
 
-    def set(self, value: float, **labels: Any) -> None:
+    def set(self, value: Any, **labels: Any) -> None:
         with self._lock:
-            self._series[_key(labels)] = float(value)
+            self._series[_key(labels)] = value
 
     def inc(self, amount: float = 1.0, **labels: Any) -> None:
         k = _key(labels)
         with self._lock:
-            self._series[k] = self._series.get(k, 0.0) + amount
+            self._series[k] = float(self._series.get(k, 0.0)) + amount
 
     def value(self, **labels: Any) -> float:
         with self._lock:
-            return self._series.get(_key(labels), 0.0)
+            v = self._series.get(_key(labels), 0.0)
+        return float(v)
 
     def _snapshot_series(self) -> list[dict]:
         with self._lock:
-            return [{"labels": dict(k), "value": v}
-                    for k, v in self._series.items()]
+            series = list(self._series.items())
+        return [{"labels": dict(k), "value": float(v)} for k, v in series]
 
 
 class Histogram(Metric):

@@ -3,8 +3,7 @@
 ``render(tracer=..., metrics=..., farm_stats=...)`` produces the human
 "where did the time go" view the paper's figures are built from:
 
-  * span breakdown — per-name count/total/mean/max, with the superstep
-    phases (``splitPre``/``splitAtt``/``splitPost``) as ordinary rows;
+  * span breakdown — per-name count/total/mean/max;
   * counter timelines — unicode sparklines of ``ph="C"`` series, e.g. the
     per-worker queued-weight trajectory behind Fig. 13's balance argument;
   * metrics — counters and gauges as lines, histograms as bar charts with

@@ -11,6 +11,15 @@ Produces the `Trace Event Format`_ consumed by Perfetto
   * :meth:`Tracer.begin` / :meth:`Tracer.end` — async spans that may cross
     threads and overlap (one per serving request, keyed by uid).
 
+An enabled span also enters a :class:`jax.profiler.TraceAnnotation` of the
+same name, so farm, ensemble and service spans land in a JAX profiler trace
+on the profiler's own clock, beside the device's operations.
+:func:`annotation` is the always-on form for program code that has no
+tracer (``frontier.build``): it costs next to nothing while no profiler
+runs.  :func:`hlo_scopes` reads a compiled module's text back to the
+``jax.named_scope`` that owns each instruction, which is how a device
+trace's operations (named by HLO instruction) are given to those scopes.
+
 Zero-cost when disabled: every method checks ``self.enabled`` first and
 returns a shared no-op, so instrumented hot paths (the farm worker loop,
 the engine tick) pay one attribute load + branch.  :data:`NULL` is the
@@ -24,9 +33,134 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 import time
-from typing import Any
+from typing import Any, NamedTuple
+
+from jax.profiler import TraceAnnotation
+
+
+def annotation(name: str) -> TraceAnnotation:
+    """A host span in the JAX profiler's trace (a no-op while none runs)."""
+    return TraceAnnotation(name)
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = .*? ([a-z][a-z0-9-]*)\((.*)$")
+_HLO_CALLED = re.compile(r"(?:calls|to_apply)=%([^\s,)}]+)")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
+# Instructions that only hold the program together: they take a scope from
+# their own metadata alone.
+_HLO_SCAFFOLDING = frozenset((
+    "parameter", "get-tuple-element", "tuple", "while", "conditional",
+    "call"))
+
+
+class HloScope(NamedTuple):
+    """The scope an instruction runs under, and the rule of
+    :func:`hlo_scopes` (1-4) that found it: 1 and 2 read the program's own
+    metadata, 3 and 4 infer the scope of an instruction that has none."""
+
+    scope: str
+    rule: int
+
+
+def hlo_scopes(hlo_text: str, prefix: str,
+               entry_scope: str | None = None) -> dict[str, HloScope]:
+    """``{instruction name: innermost scope}`` of a compiled HLO module.
+
+    ``hlo_text`` is ``jax.stages.Compiled.as_text()``.  A scope is a path
+    element of an ``op_name`` that starts with ``prefix``; ``jax.named_scope``
+    puts it there.  An instruction's scope is, by the first rule that finds
+    one:
+
+    1. the innermost one in its own ``op_name``;
+    2. the first found in what it calls (a fusion's fused instructions,
+       root first);
+    3. for an instruction the compiler made without metadata (a layout
+       copy, a broadcast of a constant, a piece of a decomposed scan), the
+       scope of an instruction of its computation that uses its result
+       (through a tuple: the loop or branch it is packed for), else of one
+       it reads;
+    4. in the entry computation, ``entry_scope``: for a program that is
+       set-up and one loop, what the compiler made to fill the loop's
+       initial state is set-up.
+
+    Loop scaffolding (parameters, tuples, ``while``, ``conditional``) takes
+    only a scope of its own; instructions that find none are left out.
+    """
+    comps: dict[str, list[tuple]] = {}
+    entry = None
+    cur: list | None = None
+    for line in hlo_text.splitlines():
+        if cur is None or not line.startswith(" "):
+            m = _HLO_COMPUTATION.match(line)
+            cur = comps.setdefault(m.group(1), []) if m else None
+            if m and line.startswith("ENTRY "):
+                entry = m.group(1)
+            continue
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            continue
+        name, opcode, rest = m.groups()
+        op_name = _HLO_OP_NAME.search(rest)
+        own = [p for p in (op_name.group(1) if op_name else "").split("/")
+               if p.startswith(prefix)]
+        called = ([] if opcode in _HLO_SCAFFOLDING
+                  else _HLO_CALLED.findall(rest))
+        cur.append((name, opcode, re.findall(r"%([^\s,)}]+)", rest), called,
+                    own[-1] if own else None))
+
+    first: dict[str, str | None] = {}
+
+    def called_scope(called: list[str]) -> str | None:
+        return next(filter(None, map(first_scope, called)), None)
+
+    def first_scope(comp: str) -> str | None:
+        if comp not in first:
+            first[comp] = None                   # a cycle finds nothing
+            first[comp] = next(filter(None, (
+                own or called_scope(called)
+                for _, _, _, called, own in reversed(comps.get(comp, ())))),
+                None)
+        return first[comp]
+
+    out: dict[str, HloScope] = {}
+    for comp, instrs in comps.items():
+        scope: dict[str, HloScope] = {}
+        for name, _, _, called, own in instrs:
+            if own:
+                scope[name] = HloScope(own, 1)
+            elif found := called_scope(called):
+                scope[name] = HloScope(found, 2)
+        users: dict[str, list[str]] = {}
+        for name, _, operands, _, _ in instrs:
+            for o in operands:
+                users.setdefault(o, []).append(name)
+        tuples = {n for n, opcode, _, _, _ in instrs if opcode == "tuple"}
+        for used_by in users.values():
+            used_by += [u2 for u in used_by if u in tuples
+                        for u2 in users.get(u, ())]
+        loose = [(n, users.get(n, []) + operands)
+                 for n, opcode, operands, _, _ in instrs
+                 if n not in scope and opcode not in _HLO_SCAFFOLDING]
+        while loose:                             # until nothing changes
+            still = []
+            for name, near in loose:
+                found = next((scope[n].scope for n in near if n in scope),
+                             None)
+                if found:
+                    scope[name] = HloScope(found, 3)
+                else:
+                    still.append((name, near))
+            if len(still) == len(loose):
+                break
+            loose = still
+        if comp == entry and entry_scope:
+            scope.update((name, HloScope(entry_scope, 4)) for name, _ in loose)
+        out.update(scope)
+    return out
 
 
 class _NullSpan:
@@ -47,7 +181,7 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One open duration span; emits a single complete ("X") event on exit."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict | None):
         self._tracer = tracer
@@ -55,12 +189,15 @@ class _Span:
         self._args = args
 
     def __enter__(self) -> "_Span":
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         tr = self._tracer
         t1 = tr._now_us()
+        self._annotation.__exit__(*exc)
         ev = {"name": self._name, "ph": "X", "ts": self._t0,
               "dur": t1 - self._t0, "pid": tr._pid, "tid": tr._tid()}
         if self._args:

@@ -1,8 +1,12 @@
 """Observability layer: tracer, metrics registry, report, instrumented runs."""
 
+import glob
 import json
+import re
 import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -14,8 +18,8 @@ from repro.core.farm import FaultPolicy
 from repro.core.faults import FaultInjector, FaultSpec
 from repro.core.tree import trees_equal
 from repro.obs import report
-from repro.obs.metrics import DEFAULT_BUCKETS, Registry
-from repro.obs.trace import NULL, Tracer, _NULL_SPAN
+from repro.obs.metrics import DEFAULT_BUCKETS, Gauge, Registry
+from repro.obs.trace import NULL, Tracer, _NULL_SPAN, hlo_scopes
 
 
 # ---------------------------------------------------------------- tracer
@@ -208,22 +212,216 @@ def test_traced_frontier_build_matches_untraced():
     plain = frontier.build(ds, cfg)
     tr = Tracer()
     reg = Registry()
-    traced, stats = frontier.build(ds, cfg, collect_stats=True,
-                                   tracer=tr, metrics=reg)
+    with tr.span("grow"):
+        traced, stats = frontier.build(ds, cfg, collect_stats=True,
+                                       metrics=reg)
     assert trees_equal(plain, traced)
 
-    names = {e["name"] for e in tr.events if e["ph"] == "X"}
-    assert {"superstep", "splitPre", "splitAtt", "splitPost"} <= names
     summ = tr.span_summary()
+    assert summ["grow"]["count"] == 1
     n_steps = len(stats)
-    assert summ["superstep"]["count"] == n_steps
-    assert summ["splitAtt"]["count"] == n_steps
+    assert reg.gauge("frontier_supersteps").value() == n_steps
+    assert reg.gauge("frontier_open_nodes").value() == traced.size
+    assert reg.gauge("frontier_cases").value() == ds.n_cases
+    assert reg.get("frontier_phase_seconds") is None
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_fused_build_counters_equal_the_stepwise_rows(impl):
+    ds = make_tree_dataset(np.random.default_rng(4), n=1500)
+    cfg = GrowConfig(max_nodes=2048, frontier_slots=8, compact_min_bucket=128)
+    reg = Registry()
+    fused = frontier.build(ds, cfg, impl=impl, metrics=reg)
+    stepwise, rows = frontier.build(ds, cfg, impl=impl, collect_stats=True,
+                                    metrics=Registry())
+    assert trees_equal(fused, stepwise)
+    got = {name: reg.gauge(name).value() for name in (
+        "frontier_supersteps", "frontier_open_nodes",
+        "frontier_live_case_steps", "frontier_hist_case_steps",
+        "frontier_cases", "frontier_slots")}
+    assert got == {
+        "frontier_supersteps": len(rows),
+        "frontier_open_nodes": sum(r["n_processed"] for r in rows),
+        "frontier_live_case_steps": sum(r["n_active"] for r in rows),
+        "frontier_hist_case_steps": sum(r["n_hist"] for r in rows),
+        "frontier_cases": ds.n_cases,
+        "frontier_slots": cfg.frontier_slots}
+    assert rows[0]["n_active"] == ds.n_cases          # the root holds all
+    if impl == "jnp":                                 # nothing is gathered
+        assert all(r["n_hist"] == ds.n_cases for r in rows)
+    else:                                             # a bucket holds them
+        assert all(r["n_active"] <= r["n_hist"] <= ds.n_cases for r in rows)
+        assert any(r["n_hist"] < ds.n_cases for r in rows)
+
+
+def test_wide_totals_pass_2_pow_31_exactly():
+    words = jnp.zeros((2,), jnp.int32)
+    steps = [2**31 - 1, 2**31 - 1, 2**30, 12345, 2**30 - 1, 0]
+    for v in steps:
+        words = frontier._wide_add(words, jnp.int32(v))
+    assert float(frontier._WideTotal(words)) == sum(steps)
+    assert int(words[1]) < 2**frontier.LOW_BITS
+
+
+def test_build_leaves_device_values_in_the_registry(monkeypatch):
+    seen = {}
+    set_value = Gauge.set
+
+    def spy(self, value, **labels):
+        seen[self.name] = value
+        set_value(self, value, **labels)
+
+    monkeypatch.setattr(Gauge, "set", spy)
+    ds = make_tree_dataset(np.random.default_rng(6), n=300)
+    reg = Registry()
+    tree = frontier.build(ds, GrowConfig(max_depth=4), metrics=reg)
+    assert isinstance(seen["frontier_supersteps"], jax.Array)
+    assert isinstance(seen["frontier_open_nodes"], jax.Array)
+    for name in ("frontier_live_case_steps", "frontier_hist_case_steps"):
+        assert isinstance(seen[name].words, jax.Array)
+    assert reg.gauge("frontier_open_nodes").value() == tree.size
     snap = reg.snapshot()
-    assert snap["frontier_supersteps_total"]["series"][0]["value"] == n_steps
-    phase = snap["frontier_phase_seconds"]["series"]
-    assert {tuple(s["labels"].items())[0][1] for s in phase} == \
-        {"splitPre", "splitAtt", "splitPost"}
-    assert all(s["count"] == n_steps for s in phase)
+    assert all(isinstance(m["series"][0]["value"], float)
+               for m in snap.values())
+
+
+# The loop's scaffolding, which owns no work of a phase.
+SCAFFOLDING = {"while", "conditional", "tuple", "parameter",
+               "get-tuple-element", "copy"}
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = .*? ([a-z][a-z0-9-]*)\(")
+
+
+def _instructions_that_run(hlo_text):
+    """(name, opcode) of every instruction in a computation the program
+    runs as such: the entry, loop conditions and bodies, branches and
+    called computations, not what a fusion or a reduction applies."""
+    comps, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            entry = m.group(1) if line.startswith("ENTRY") else entry
+        elif cur is not None and (mi := _INSTRUCTION.match(line)):
+            cur.append((mi.group(1), mi.group(2), line))
+    seen, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for _, opcode, line in comps[comp]:
+            todo += re.findall(r"(?:condition|body)=%([^\s,)]+)", line)
+            for branches in re.findall(r"branch_computations=\{([^}]*)\}",
+                                       line):
+                todo += [b.strip().lstrip("%") for b in branches.split(",")]
+            if opcode == "call":
+                todo += re.findall(r"to_apply=%([^\s,)]+)", line)
+    return [(n, op) for c in seen for n, op, _ in comps[c]]
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_every_build_instruction_has_a_frontier_scope(impl):
+    ds = make_tree_dataset(np.random.default_rng(9), n=1200)
+    cfg = GrowConfig(max_nodes=1024, frontier_slots=8, compact_min_bucket=128)
+    frontier._DISPATCHED.clear()
+    frontier.build(ds, cfg, impl=impl)
+    ((prob, _, specs),) = frontier._DISPATCHED
+    text = frontier._build_jit.lower(
+        *[jax.ShapeDtypeStruct(s, d) for s, d in specs], prob=prob,
+        impl=impl).compile().as_text()
+    scopes = frontier.build_scopes()
+    ops = _instructions_that_run(text)
+    assert len(ops) > 50
+    missing = [(n, op) for n, op in ops
+               if n not in scopes and op not in SCAFFOLDING]
+    assert missing == []
+    phases = {s.scope for s in scopes.values()}
+    assert {s.rule for s in scopes.values()} <= {1, 2, 3, 4}
+    assert {"frontier.init", "frontier.split_pre", "frontier.select",
+            "frontier.split_att", "frontier.compact", "frontier.split_post",
+            "frontier.route"} <= phases
+    assert phases <= {"frontier.init", "frontier.split_pre",
+                      "frontier.select", "frontier.split_att",
+                      "frontier.compact", "frontier.split_post",
+                      "frontier.route"}
+
+
+def test_hlo_scopes_reads_own_fused_and_neighbouring_scopes():
+    text = """HloModule m
+
+%fused (p: s32[4]) -> s32[4] {
+  %p = s32[4]{0} parameter(0)
+  ROOT %n = s32[4]{0} negate(%p), metadata={op_name="jit(f)/while/body/f.b/f.c/neg"}
+}
+
+%body (t: (s32[4])) -> (s32[4]) {
+  %t = (s32[4]{0}) parameter(0)
+  %g = s32[4]{0} get-tuple-element(%t), index=0
+  %made = s32[4]{0} copy(%g)
+  %fusion.1 = s32[4]{0} fusion(%made), kind=kLoop, calls=%fused
+  %own = s32[4]{0} add(%fusion.1, %fusion.1), metadata={op_name="jit(f)/while/body/f.a/add"}
+  %spare = s32[4]{0} copy(%g)
+  ROOT %r = (s32[4]{0}) tuple(%own)
+}
+
+%cond (t: (s32[4])) -> pred[] {
+  %t.1 = (s32[4]{0}) parameter(0)
+  ROOT %c = pred[] constant(true)
+}
+
+ENTRY %main (x: s32[4]) -> (s32[4]) {
+  %x = s32[4]{0} parameter(0), metadata={op_name="x"}
+  %fill = s32[4]{0} broadcast(%x), dimensions={}
+  %init = (s32[4]{0}) tuple(%fill)
+  ROOT %w = (s32[4]{0}) while(%init), condition=%cond, body=%body, metadata={op_name="jit(f)/while"}
+}
+"""
+    got = hlo_scopes(text, "f.", entry_scope="f.init")
+    assert got["n"] == ("f.c", 1)              # innermost of its own
+    assert got["fusion.1"] == ("f.c", 2)       # from what it fuses
+    assert got["own"] == ("f.a", 1)
+    assert got["made"] == ("f.c", 3)           # from the fusion that uses it
+    assert "spare" not in got                  # no neighbour has a scope
+    assert got["fill"] == ("f.init", 4)        # the loop's initial state
+    for scaffolding in ("w", "init", "t", "g", "r", "x"):
+        assert scaffolding not in got
+    assert "fill" not in hlo_scopes(text, "f.")   # no entry scope given
+
+
+def test_enabled_span_lands_in_the_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs.test_span"):
+            (jnp.arange(8) * 2).block_until_ready()
+        with NULL.span("obs.null_span"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    assert "obs.test_span" in names
+    assert "obs.null_span" not in names
+    assert tr.span_summary()["obs.test_span"]["count"] == 1
+
+
+def test_build_annotations_land_in_the_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+    ds = make_tree_dataset(np.random.default_rng(8), n=200)
+    cfg = GrowConfig(max_depth=3)
+    frontier.build(ds, cfg)                       # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        frontier.build(ds, cfg)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    assert {"frontier.build", "frontier.to_device"} <= names
 
 
 def test_traced_farm_chaos_build_matches_oracle(tmp_path):
@@ -258,6 +456,7 @@ def test_tracing_disabled_leaves_no_residue():
     cfg = GrowConfig(max_depth=4)
     n0 = len(NULL.events)
     a = frontier.build(ds, cfg)
-    b = frontier.build(ds, cfg, tracer=NULL)
+    with NULL.span("grow"):
+        b = frontier.build(ds, cfg)
     assert trees_equal(a, b)
     assert len(NULL.events) == n0
