@@ -53,6 +53,7 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace
 
 EPS_W = entropy.EPS_W
+MAX_NODES = 1 << 24         # node ids route through float32 (see route())
 
 # A total of cases over supersteps passes 2**31 at the paper's sizes (10M
 # cases x ~700 supersteps), so it is carried as (high, low) int32 words with
@@ -111,6 +112,12 @@ class FrontierProblem:
     n_classes: int
     max_children: int        # H: >= 2 and >= widest discrete split
     cfg: GrowConfig
+
+    def __post_init__(self):
+        if self.cfg.max_nodes > MAX_NODES:
+            raise ValueError(f"max_nodes {self.cfg.max_nodes} is over "
+                             f"{MAX_NODES}: routing reads node ids in "
+                             "float32, exact only up to 2**24")
 
     @staticmethod
     def from_dataset(ds: BinnedDataset, cfg: GrowConfig) -> "FrontierProblem":
@@ -366,12 +373,13 @@ def split_post(state: GrowState, pre: dict, att: dict,
 
     # ---- scatter node results ----------------------------------------------
     write_ids = jnp.where(valid, ids, m)                      # m = dropped
+    node_attr = jnp.where(internal, best_attr, -1)            # (K,)
+    node_thr = jnp.where(internal & is_cont, sb, -1)          # (K,)
     tree = dataclasses.replace(
         tree,
-        node_attr=tree.node_attr.at[write_ids].set(
-            jnp.where(internal, best_attr, -1), mode="drop"),
+        node_attr=tree.node_attr.at[write_ids].set(node_attr, mode="drop"),
         node_split_bin=tree.node_split_bin.at[write_ids].set(
-            jnp.where(internal & is_cont, sb, -1), mode="drop"),
+            node_thr, mode="drop"),
         node_child0=tree.node_child0.at[write_ids].set(
             jnp.where(internal, child0, 0), mode="drop"),
         node_nchild=tree.node_nchild.at[write_ids].set(nch, mode="drop"),
@@ -406,24 +414,8 @@ def split_post(state: GrowState, pre: dict, att: dict,
                          (k, h_dim, a_dim)).reshape(-1, a_dim), mode="drop")
 
     # ---- route cases to their child (the feedback edge) --------------------
-    with jax.named_scope("frontier.route"):
-        part = slot >= 0
-        slot_safe = jnp.maximum(slot, 0)
-        a_case = best_attr[slot_safe]
-        # Row-local select of x[i, a_case[i]].  A take_along_axis here makes
-        # the SPMD partitioner materialise replicated (N, 1, 2) gather
-        # indices plus an all-reduce of the result — 120 MB/superstep of pure
-        # routing traffic (measured).  The one-hot contraction is elementwise
-        # row-local: zero collectives, A x s32 reads (A = 9).
-        onehot_a = (jnp.arange(a_dim, dtype=jnp.int32)[None, :]
-                    == a_case[:, None])
-        b_case = jnp.sum(jnp.where(onehot_a, x, 0), axis=1)
-        j_cont = jnp.where(b_case <= sb[slot_safe], 0, 1)
-        j_case = jnp.where(is_cont[slot_safe], j_cont, b_case)
-        j_case = jnp.where(b_case < 0, heaviest[slot_safe], j_case)
-        new_node = child0[slot_safe] + j_case
-        case_node = jnp.where(part & internal[slot_safe], new_node,
-                              state.case_node).astype(jnp.int32)
+    case_node = route(state.case_node, slot, x, attr=node_attr,
+                      thr=node_thr, heaviest=heaviest, child0=child0)
 
     n_processed = jnp.sum(valid.astype(jnp.int32))
     new_state = GrowState(
@@ -449,6 +441,53 @@ def split_post(state: GrowState, pre: dict, att: dict,
             alpha=cfg.alpha).astype(jnp.int32) * valid.astype(jnp.int32)),
     )
     return new_state, stats
+
+
+def route(case_node: jnp.ndarray, slot: jnp.ndarray, x: jnp.ndarray, *,
+          attr: jnp.ndarray, thr: jnp.ndarray, heaviest: jnp.ndarray,
+          child0: jnp.ndarray) -> jnp.ndarray:
+    """Each case's node after a superstep: its child where its slot split.
+
+    The per-slot routing record is four int32 (K,) fields: ``attr`` the
+    split attribute, -1 where the slot did not split (its cases keep their
+    node); ``thr`` the threshold bin of a continuous split (a case goes to
+    child 1 if its bin is above it), -1 for a discrete one (the bin is the
+    child); ``heaviest`` the child that takes unknown values (bin -1); and
+    ``child0`` the slot's first child.  ``slot`` is -1 for a case in no
+    open node.
+    """
+    k = attr.shape[0]
+    a_dim = x.shape[1]
+    with jax.named_scope("frontier.route"):
+        # Each case looks its slot's record up by one one-hot matmul over
+        # the K slots, not by gathers.  A gather pays a fixed cost per
+        # looked-up index: on a TPU v5e (N = 500,000, K = 256) six gathers
+        # of per-slot fields took 23.7 ms a superstep, a compare-and-select
+        # over the K slots 0.8 ms and this matmul 0.25 ms.  Its work grows
+        # as N·K against the gathers' N, so it stays ahead well past any
+        # configured K.  Exactly one slot matches (none where slot == -1),
+        # and float32 at HIGHEST precision carries integers below 2**24
+        # exactly (FrontierProblem bounds the node ids).  The record is
+        # replicated and each case's column is its own, so a build sharded
+        # over cases adds no collective over them.
+        hit = (jnp.arange(k, dtype=jnp.int32)[:, None]
+               == slot[None, :]).astype(jnp.float32)          # (K, N)
+        rec = jnp.stack([attr, thr, heaviest, child0]).astype(jnp.float32)
+        a_case, t_case, h_case, c_case = jnp.dot(
+            rec, hit, precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+        # Row-local select of x[i, a_case[i]].  A take_along_axis here makes
+        # the SPMD partitioner materialise replicated (N, 1, 2) gather
+        # indices plus an all-reduce of the result — 120 MB/superstep of pure
+        # routing traffic (measured).  The one-hot contraction is elementwise
+        # row-local: zero collectives, A x s32 reads (A = 9).
+        onehot_a = (jnp.arange(a_dim, dtype=jnp.int32)[None, :]
+                    == a_case[:, None])
+        b_case = jnp.sum(jnp.where(onehot_a, x, 0), axis=1)
+        j_case = jnp.where(t_case >= 0, (b_case > t_case).astype(jnp.int32),
+                           b_case)
+        j_case = jnp.where(b_case < 0, h_case, j_case)
+        return jnp.where((slot >= 0) & (a_case >= 0), c_case + j_case,
+                         case_node).astype(jnp.int32)
 
 
 def superstep(

@@ -320,9 +320,9 @@ def _instructions_that_run(hlo_text):
     return [(n, op) for c in seen for n, op, _ in comps[c]]
 
 
-@pytest.mark.parametrize("impl", ["jnp", "pallas"])
-def test_every_build_instruction_has_a_frontier_scope(impl):
-    ds = make_tree_dataset(np.random.default_rng(9), n=1200)
+def _small_build_program(impl, n_cases):
+    """The compiled text of a small build and its ``build_scopes()``."""
+    ds = make_tree_dataset(np.random.default_rng(9), n=n_cases)
     cfg = GrowConfig(max_nodes=1024, frontier_slots=8, compact_min_bucket=128)
     frontier._DISPATCHED.clear()
     frontier.build(ds, cfg, impl=impl)
@@ -330,7 +330,12 @@ def test_every_build_instruction_has_a_frontier_scope(impl):
     text = frontier._build_jit.lower(
         *[jax.ShapeDtypeStruct(s, d) for s, d in specs], prob=prob,
         impl=impl).compile().as_text()
-    scopes = frontier.build_scopes()
+    return text, frontier.build_scopes()
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_every_build_instruction_has_a_frontier_scope(impl):
+    text, scopes = _small_build_program(impl, 1200)
     ops = _instructions_that_run(text)
     assert len(ops) > 50
     missing = [(n, op) for n, op in ops
@@ -345,6 +350,24 @@ def test_every_build_instruction_has_a_frontier_scope(impl):
                       "frontier.select", "frontier.split_att",
                       "frontier.compact", "frontier.split_post",
                       "frontier.route"}
+
+
+_GATHER = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[(\d+)[\],].*? gather\(",
+                     re.MULTILINE)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_route_gathers_nothing_over_the_cases(impl):
+    # Routing looks each case's per-slot record up by a one-hot matmul
+    # over the K slots: no gather with a result over the N cases may carry
+    # the route's scope.  splitPre's node_to_slot[case_node] is one, which
+    # shows that the search finds such gathers.
+    n = 1200
+    text, scopes = _small_build_program(impl, n)
+    over_cases = [name for name, dim in _GATHER.findall(text)
+                  if int(dim) == n]
+    assert over_cases
+    assert [g for g in over_cases if scopes[g].scope == "frontier.route"] == []
 
 
 def test_hlo_scopes_reads_own_fused_and_neighbouring_scopes():

@@ -6,6 +6,8 @@ cannot lower, or a VMEM plan over the limit fails here instead of on the
 chip.  Nothing runs: results and speed come only from ``chip_smoke.py`` on a
 real chip.  Shapes: Table-1 widths at K=256 frontier slots and the tiles
 autotune plans for them; the traversal at the smoke test's forest shape.
+The frontier's case routing is compiled too, to show that the chip's
+compiler leaves it without a gather.
 """
 
 import os
@@ -15,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import frontier
 from repro.kernels import autotune, histogram, split_gain, tree_infer
 
 N_CASES = 1 << 20
@@ -103,3 +106,15 @@ def test_traversal_compiles_for_v5e(spec):
         spec((rows, attrs), jnp.int32), spec((attrs,), jnp.bool_),
         max_depth=levels, block_n=plan.block_n, chunk=plan.chunk).compile()
     _assert_mosaic(compiled, "forest_predict")
+
+
+def test_route_compiles_for_v5e_without_a_gather(spec):
+    a = WIDTHS["syd10m9a"][0]
+    route = jax.jit(lambda case_node, slot, x, attr, thr, heaviest, child0:
+                    frontier.route(case_node, slot, x, attr=attr, thr=thr,
+                                   heaviest=heaviest, child0=child0))
+    per_case = spec((N_CASES,), jnp.int32)
+    per_slot = spec((SLOTS,), jnp.int32)
+    compiled = route.lower(per_case, per_case, spec((N_CASES, a), jnp.int32),
+                           per_slot, per_slot, per_slot, per_slot).compile()
+    assert " gather(" not in compiled.as_text()
