@@ -305,9 +305,15 @@ def split_att(state: GrowState, pre: dict,
     active_k = state.active[pre["ids_safe"]] & pre["valid"][:, None]
     best_attr, best_score, has_split = entropy.pick_best_attribute(
         score, active_k)
+    # (slot, attribute) pairs C4.5 scores: an open node it does not stop
+    # first, an active attribute.  The histogram and the gain pass compute
+    # all K x A of them.  Only the stepwise rows read this; the fused build
+    # leaves it to _ScoredPairs, which counts the same pairs from the tree.
+    n_tested = jnp.sum((active_k & ~pre["pre_leaf"][:, None])
+                       .astype(jnp.int32))
     return dict(hist=hist, unknown=unknown, split_bin=split_bin,
                 active_k=active_k, best_attr=best_attr, has_split=has_split,
-                n_live=n_live, n_hist=n_hist)
+                n_live=n_live, n_hist=n_hist, n_tested=n_tested)
 
 
 def split_post(state: GrowState, pre: dict, att: dict,
@@ -432,6 +438,7 @@ def split_post(state: GrowState, pre: dict, att: dict,
         n_processed=n_processed,
         n_active=att["n_live"],
         n_hist=att["n_hist"],
+        n_tested=att["n_tested"],
         n_internal=jnp.sum(internal.astype(jnp.int32)),
         n_children=total_children,
         max_r=jnp.max(jnp.where(valid, total_w, 0.0)),
@@ -568,10 +575,51 @@ def build_scopes() -> dict[str, trace.HloScope]:
     return out
 
 
-def _publish(state: GrowState, prob: FrontierProblem,
+@dataclasses.dataclass(frozen=True)
+class _ScoredPairs:
+    """The (slot, attribute) pairs a build's supersteps scored, counted on
+    the host from its tree when ``float()`` reads them.  Each grown node
+    took a slot once, and C4.5 scored it unless ``split_pre``'s stop tests
+    (pure, small, deep) stopped it first, over the attributes active there:
+    those ``attr_mask`` allows, less the discrete ones split on above it.
+    It holds only the tree the build returned and host values, so the
+    device does nothing for it and keeps nothing more alive."""
+
+    tree: Tree
+    attr_is_cont: np.ndarray   # bool (A,)
+    attr_mask: Any             # bool (A,), or None for every attribute
+    cfg: GrowConfig
+
+    def __float__(self) -> float:
+        t = self.tree.to_numpy()
+        n = int(t.n_nodes)
+        nch, attr, depth = t.node_nchild[:n], t.node_attr[:n], t.node_depth[:n]
+        parent = np.zeros(n, np.int64)
+        first = np.repeat(t.node_child0[:n], nch)
+        parent[first + np.arange(first.size)
+               - np.repeat(np.cumsum(nch) - nch, nch)] = np.repeat(
+                   np.arange(n), nch)
+        active = np.ones((n, self.attr_is_cont.size), bool)
+        if self.attr_mask is not None:
+            active[0] = np.asarray(self.attr_mask, bool)
+        for d in range(1, int(depth.max(initial=0)) + 1):
+            kids = np.flatnonzero(depth == d)
+            up = parent[kids]
+            active[kids] = active[up]
+            disc = ~self.attr_is_cont[attr[up]]
+            active[kids[disc], attr[up][disc]] = False
+        freq = t.node_freq[:n]
+        stopped = ((np.sum(freq > EPS_W, -1) <= 1)
+                   | (freq.sum(-1, dtype=np.float32) < 2.0 * self.cfg.min_objs)
+                   | (depth >= self.cfg.max_depth))
+        return float(np.sum(active[~stopped]))
+
+
+def _publish(state: GrowState, prob: FrontierProblem, scored: _ScoredPairs,
              reg: obs_metrics.Registry) -> None:
     """The one writer of the ``frontier_*`` gauges: the last build's totals,
-    as device values that are read only when the registry is read."""
+    as device values (or its tree) that are read only when the registry is
+    read."""
     reg.gauge("frontier_supersteps",
               "supersteps of the last build").set(state.supersteps)
     reg.gauge("frontier_open_nodes",
@@ -582,10 +630,15 @@ def _publish(state: GrowState, prob: FrontierProblem,
     reg.gauge("frontier_hist_case_steps",
               "sum over the last build's supersteps of the cases the "
               "histogram was given").set(_WideTotal(state.hist_steps))
+    reg.gauge("frontier_tested_pairs",
+              "sum over the last build's supersteps of the (slot, attribute) "
+              "pairs C4.5 scored").set(scored)
     reg.gauge("frontier_cases",
               "training cases of the last build").set(prob.n_cases)
     reg.gauge("frontier_slots",
               "frontier slots of the last build").set(prob.cfg.frontier_slots)
+    reg.gauge("frontier_attrs",
+              "attributes of the last build").set(prob.n_attrs)
 
 
 def build(ds: BinnedDataset, cfg: GrowConfig = GrowConfig(), *,
@@ -598,17 +651,19 @@ def build(ds: BinnedDataset, cfg: GrowConfig = GrowConfig(), *,
     The build is one jitted ``while_loop`` over supersteps.  It carries its
     totals (supersteps, open nodes processed, cases in an open node and
     cases the histogram was given, summed over supersteps) and writes them,
-    N and K to the ``frontier_*`` gauges of ``metrics`` (default the
-    process-wide :data:`repro.obs.metrics.REGISTRY`) without waiting for
-    the device.  Its host work runs under the profiler annotations
-    ``frontier.build`` and ``frontier.to_device`` (the copy of the training
-    set that every call makes).
+    the (slot, attribute) pairs its supersteps scored (counted on the host
+    from the tree when read), N, K and A to the ``frontier_*`` gauges of ``metrics``
+    (default the process-wide :data:`repro.obs.metrics.REGISTRY`) without
+    waiting for the device.  Its host work runs under the profiler
+    annotations ``frontier.build`` and ``frontier.to_device`` (the copy of
+    the training set that every call makes).
 
     With ``collect_stats=True`` the superstep loop runs host-side instead
     and also returns one row of scheduling statistics per superstep (NP vs
     NAP decisions per the configured cost model — the data behind paper
     Fig. 15; ``n_active`` and ``n_hist`` are the live cases and the cases
-    the histogram was given).
+    the histogram was given, ``n_tested`` the (slot, attribute) pairs
+    scored).
 
     ``attr_mask`` (bool (A,)) restricts the split search to a subset of
     attributes; ``case_w`` (f32 (N,)) overrides the per-case weights — the
@@ -642,9 +697,11 @@ def build(ds: BinnedDataset, cfg: GrowConfig = GrowConfig(), *,
             _DISPATCHED.setdefault(
                 (prob, impl, tuple((a.shape, a.dtype) for a in args)))
             state = _build_jit(*args, prob=prob, impl=impl)
+        tree = dataclasses.replace(state.tree, n_nodes=state.n_nodes)
         _publish(state, prob,
+                 _ScoredPairs(tree, np.asarray(ds.attr_is_cont, bool),
+                              attr_mask, cfg),
                  obs_metrics.REGISTRY if metrics is None else metrics)
-    tree = dataclasses.replace(state.tree, n_nodes=state.n_nodes)
     return (tree, rows) if collect_stats else tree
 
 

@@ -210,8 +210,8 @@ def _yadt_cell(shape_name: str, mesh: Mesh) -> Cell:
         return new_state, stats
 
     stats_sh = {k: rep for k in ("n_processed", "n_active", "n_hist",
-                                 "n_internal", "n_children", "max_r",
-                                 "nap_nodes")}
+                                 "n_tested", "n_internal", "n_children",
+                                 "max_r", "nap_nodes")}
     return Cell("yadt", shape, superstep,
                 (state, x, y, w, cont, nb),
                 (state_sh, case2_sh, case_sh, case_sh, rep, rep),

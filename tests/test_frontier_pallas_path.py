@@ -38,9 +38,12 @@ def kernel_spies(monkeypatch):
     return calls
 
 
-# Table-1 stand-ins at CPU scale: one wide-schema (40 attrs, discrete-heavy)
-# and one QUEST-generated (9 attrs, continuous-heavy, 10M-case original).
-BUNDLED = [("census_pums", 0.001), ("syd10m9a", 0.00002)]
+# Table-1 stand-ins at CPU scale: one wide-schema (40 attrs, discrete-heavy),
+# one QUEST-generated (9 attrs, continuous-heavy, 10M-case original) and
+# one all-discrete (67 attrs of 2-11 values, 5 classes: multiway splits on
+# every level).
+BUNDLED = [("census_pums", 0.001), ("syd10m9a", 0.00002),
+           ("us_census", 0.001)]
 
 
 @pytest.mark.parametrize("name,scale", BUNDLED)
@@ -115,3 +118,25 @@ def test_split_gain_scores_match_jnp_scoring():
                                           np.asarray(b_ker))
             np.testing.assert_allclose(np.asarray(s_ker),
                                        np.asarray(s_ref), rtol=0, atol=atol)
+
+
+def test_census_tree_passes_the_float64_reference():
+    # bench/c45_ref.py recomputes every node's C4.5 decision in float64 from
+    # the raw cases and imports nothing of the program
+    from bench import c45_ref
+    ds = datasets.load("us_census", scale=0.001)
+    cfg = GrowConfig(max_nodes=4096, frontier_slots=32,
+                     compact_min_bucket=64)
+    tree = frontier.build(ds, cfg, impl="pallas").to_numpy()
+    n = tree.size
+    fields = ("node_attr", "node_split_bin", "node_child0", "node_nchild",
+              "node_class", "node_freq", "node_depth")
+    got = c45_ref.check_tree(
+        dict(x=ds.x, y=ds.y, attr_is_cont=ds.attr_is_cont, n_bins=ds.n_bins,
+             n_classes=ds.n_classes),
+        dict({f: getattr(tree, f)[:n] for f in fields}, n_nodes=n),
+        dict(min_objs=cfg.min_objs, max_depth=cfg.max_depth, eps_gain=1e-6))
+    assert (tree.node_nchild[:n] > 2).any()          # multiway splits
+    assert got["freq_mismatch_nodes"] == 0
+    assert got["structure_faults"] == 0, got["fault_kinds"]
+    assert got["gain_gap_bits"] < 1e-9

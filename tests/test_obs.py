@@ -17,6 +17,7 @@ from repro.core.config import GrowConfig
 from repro.core.farm import FaultPolicy
 from repro.core.faults import FaultInjector, FaultSpec
 from repro.core.tree import trees_equal
+from repro.data import datasets
 from repro.obs import report
 from repro.obs.metrics import DEFAULT_BUCKETS, Gauge, Registry
 from repro.obs.trace import NULL, Tracer, _NULL_SPAN, hlo_scopes
@@ -226,32 +227,74 @@ def test_traced_frontier_build_matches_untraced():
     assert reg.get("frontier_phase_seconds") is None
 
 
+def _scored_pairs(tree, ds, cfg, mask) -> int:
+    """The (node, attribute) pairs C4.5 scores in ``tree``, counted on the
+    host: every node that C4.5 does not stop first (pure, small, deep),
+    times the attributes still active there (all the mask allows but the
+    discrete ones split on above it)."""
+    t = tree.to_numpy()
+    n = tree.size
+    used = np.zeros((n, ds.n_attrs), bool)
+    used[0] = ~mask
+    for i in range(n):                        # children follow parents
+        if t.node_nchild[i]:
+            kids = slice(t.node_child0[i], t.node_child0[i] + t.node_nchild[i])
+            used[kids] = used[i]
+            if not ds.attr_is_cont[t.node_attr[i]]:
+                used[kids, t.node_attr[i]] = True
+    freq = t.node_freq[:n]
+    pre_leaf = ((np.sum(freq > frontier.EPS_W, -1) <= 1)
+                | (freq.sum(-1) < 2.0 * cfg.min_objs)
+                | (t.node_depth[:n] >= cfg.max_depth))
+    return int(np.sum(~used[~pre_leaf]))
+
+
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
 def test_fused_build_counters_equal_the_stepwise_rows(impl):
-    ds = make_tree_dataset(np.random.default_rng(4), n=1500)
+    mixed = make_tree_dataset(np.random.default_rng(4), n=1500)
+    # all discrete, multiway splits of up to 11 children, 5 classes
+    census = datasets.load("us_census", scale=0.0004)
+    for ds in (mixed, census):
+        _check_counters(ds, impl, None)
+    # attributes left out of the search are never scored
+    _check_counters(census, impl, np.arange(census.n_attrs) % 3 > 0)
+
+
+def _check_counters(ds, impl, attr_mask):
     cfg = GrowConfig(max_nodes=2048, frontier_slots=8, compact_min_bucket=128)
     reg = Registry()
-    fused = frontier.build(ds, cfg, impl=impl, metrics=reg)
+    fused = frontier.build(ds, cfg, impl=impl, metrics=reg,
+                           attr_mask=attr_mask)
     stepwise, rows = frontier.build(ds, cfg, impl=impl, collect_stats=True,
-                                    metrics=Registry())
+                                    metrics=Registry(), attr_mask=attr_mask)
+    mask = np.ones(ds.n_attrs, bool) if attr_mask is None else attr_mask
     assert trees_equal(fused, stepwise)
     got = {name: reg.gauge(name).value() for name in (
         "frontier_supersteps", "frontier_open_nodes",
         "frontier_live_case_steps", "frontier_hist_case_steps",
-        "frontier_cases", "frontier_slots")}
+        "frontier_tested_pairs", "frontier_cases", "frontier_slots",
+        "frontier_attrs")}
     assert got == {
         "frontier_supersteps": len(rows),
         "frontier_open_nodes": sum(r["n_processed"] for r in rows),
         "frontier_live_case_steps": sum(r["n_active"] for r in rows),
         "frontier_hist_case_steps": sum(r["n_hist"] for r in rows),
+        "frontier_tested_pairs": sum(r["n_tested"] for r in rows),
         "frontier_cases": ds.n_cases,
-        "frontier_slots": cfg.frontier_slots}
+        "frontier_slots": cfg.frontier_slots,
+        "frontier_attrs": ds.n_attrs}
     assert rows[0]["n_active"] == ds.n_cases          # the root holds all
     if impl == "jnp":                                 # nothing is gathered
         assert all(r["n_hist"] == ds.n_cases for r in rows)
     else:                                             # a bucket holds them
         assert all(r["n_active"] <= r["n_hist"] <= ds.n_cases for r in rows)
         assert any(r["n_hist"] < ds.n_cases for r in rows)
+    # the whole K x A grid is computed every superstep; C4.5 scores a part
+    assert rows[0]["n_tested"] == mask.sum()          # the root, every attr
+    assert all(r["n_tested"] <= cfg.frontier_slots * ds.n_attrs for r in rows)
+    assert 0 < got["frontier_tested_pairs"] < (
+        len(rows) * cfg.frontier_slots * ds.n_attrs)
+    assert got["frontier_tested_pairs"] == _scored_pairs(fused, ds, cfg, mask)
 
 
 def test_wide_totals_pass_2_pow_31_exactly():
