@@ -105,9 +105,8 @@ def run() -> list[dict]:
 
     steps: list[dict] = []
     i = 0
-    while bool(jnp.any(state.status == 1)) and i < MAX_STEPS:
-        row = {"step": i,
-               "n_open": int(jnp.sum((state.status == 1).astype(jnp.int32)))}
+    while bool(state.open_nodes < state.n_nodes) and i < MAX_STEPS:
+        row = {"step": i, "n_open": int(state.n_nodes - state.open_nodes)}
         for vname, fn in steps_fns.items():
             with tracer.span(f"superstep.{vname}", step=i):
                 (_, stats), secs = common.timed(fn, state, x, y, w, cont, nb,

@@ -13,6 +13,10 @@ drained in batches of K = ``GrowConfig.frontier_slots`` per **superstep**:
 Because open nodes are selected in ascending id order and children are
 allocated contiguously in slot order, node ids coincide exactly with the
 sequential oracle's breadth-first ids — trees are comparable elementwise.
+It follows that the open nodes are always the id range
+``[open_nodes, n_nodes)``: a superstep takes the first K of them, closes
+them, and appends their children at ``n_nodes``.  Selection is that range,
+and a case's slot is its node id less ``open_nodes`` — no search, no table.
 
 Everything is fixed-shape and jit-able; the full build is a
 ``lax.while_loop`` over supersteps.  The same tree can also be grown
@@ -86,21 +90,16 @@ class _WideTotal:
 @dataclasses.dataclass
 class GrowState:
     tree: Tree
-    status: jnp.ndarray      # int32 (M,): 0 empty, 1 open, 2 internal, 3 leaf
     active: jnp.ndarray      # bool (M, A): attributes active at each node
     case_node: jnp.ndarray   # int32 (N,): current node of each case
     n_nodes: jnp.ndarray     # int32 scalar
     overflow: jnp.ndarray    # bool scalar — capacity forced early leaves
     # totals over the supersteps run so far (build() publishes them)
     supersteps: jnp.ndarray  # int32 scalar
-    open_nodes: jnp.ndarray  # int32 scalar: open nodes processed
+    open_nodes: jnp.ndarray  # int32 scalar: open nodes processed, so the
+    #                          open nodes are the ids [open_nodes, n_nodes)
     live_steps: jnp.ndarray  # int32 (2,) wide: Σ cases in an open node
     hist_steps: jnp.ndarray  # int32 (2,) wide: Σ cases the histogram got
-
-    STATUS_EMPTY = 0
-    STATUS_OPEN = 1
-    STATUS_INTERNAL = 2
-    STATUS_LEAF = 3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,8 +147,6 @@ def _init_state(prob, y, w, attr_mask) -> GrowState:
         active = active & jnp.asarray(attr_mask, bool)[None, :]
     return GrowState(
         tree=tree,
-        status=jnp.zeros((cfg.max_nodes,), jnp.int32).at[0].set(
-            GrowState.STATUS_OPEN),
         active=active,
         case_node=jnp.zeros((prob.n_cases,), jnp.int32),
         n_nodes=jnp.int32(1),
@@ -265,13 +262,14 @@ def split_pre(state: GrowState, *, prob: FrontierProblem
 
     # ---- select up to K open nodes, FIFO by id (= breadth-first) ----------
     with jax.named_scope("frontier.select"):
-        ids = jnp.nonzero(state.status == GrowState.STATUS_OPEN,
-                          size=k, fill_value=m)[0].astype(jnp.int32)
-        valid = ids < m
+        ids = state.open_nodes + jnp.arange(k, dtype=jnp.int32)
+        valid = ids < state.n_nodes
         ids_safe = jnp.minimum(ids, m - 1)
-        node_to_slot = jnp.full((m + 1,), -1, jnp.int32).at[ids].set(
-            jnp.arange(k, dtype=jnp.int32), mode="drop")
-    slot = node_to_slot[state.case_node]                      # (N,)
+    # A case's slot is its node's place in the range; -1 in a closed node
+    # or an open node past the first K.
+    d = state.case_node - state.open_nodes
+    slot = jnp.where((d >= 0) & (d < jnp.minimum(
+        k, state.n_nodes - state.open_nodes)), d, -1)         # (N,)
 
     # ---- stop tests on stored frequencies ----------------------------------
     freq = jnp.where(valid[:, None], tree.node_freq[ids_safe], 0.0)  # (K, C)
@@ -390,9 +388,6 @@ def split_post(state: GrowState, pre: dict, att: dict,
             jnp.where(internal, child0, 0), mode="drop"),
         node_nchild=tree.node_nchild.at[write_ids].set(nch, mode="drop"),
     )
-    status = state.status.at[write_ids].set(
-        jnp.where(internal, GrowState.STATUS_INTERNAL, GrowState.STATUS_LEAF),
-        mode="drop")
 
     # ---- scatter children ---------------------------------------------------
     j = jnp.arange(h_dim, dtype=jnp.int32)[None, :]           # (1, H)
@@ -409,8 +404,6 @@ def split_post(state: GrowState, pre: dict, att: dict,
             jnp.broadcast_to(depth_k[:, None] + 1, (k, h_dim)).reshape(-1),
             mode="drop"),
     )
-    status = status.at[cids.reshape(-1)].set(GrowState.STATUS_OPEN,
-                                             mode="drop")
     child_active = state.active[ids_safe]                     # (K, A)
     child_active = child_active & ~(
         (~is_cont)[:, None]
@@ -426,7 +419,7 @@ def split_post(state: GrowState, pre: dict, att: dict,
     n_processed = jnp.sum(valid.astype(jnp.int32))
     new_state = GrowState(
         tree=dataclasses.replace(tree, n_nodes=state.n_nodes + total_children),
-        status=status, active=active, case_node=case_node,
+        active=active, case_node=case_node,
         n_nodes=state.n_nodes + total_children,
         overflow=state.overflow | overflow,
         supersteps=state.supersteps + 1,
@@ -534,7 +527,7 @@ def _build_jit(x, y, w, attr_mask, attr_is_cont, n_bins, *,
     def cond(state):
         with jax.named_scope("frontier.split_pre"), \
                 jax.named_scope("frontier.select"):
-            return jnp.any(state.status == GrowState.STATUS_OPEN)
+            return state.open_nodes < state.n_nodes
 
     def body(state):
         new_state, _ = step(state, x, y, w, attr_is_cont, n_bins)
@@ -689,7 +682,7 @@ def build(ds: BinnedDataset, cfg: GrowConfig = GrowConfig(), *,
         if collect_stats:
             fused = jax.jit(_superstep_fn(prob, impl))
             state = init_state(prob, y, w, mask)
-            while bool(jnp.any(state.status == GrowState.STATUS_OPEN)):
+            while bool(state.open_nodes < state.n_nodes):
                 state, stats = fused(state, x, y, w, cont, nb)
                 rows.append({k: np.asarray(v).item()
                              for k, v in stats.items()})

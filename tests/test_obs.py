@@ -402,15 +402,20 @@ _GATHER = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[(\d+)[\],].*? gather\(",
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
 def test_route_gathers_nothing_over_the_cases(impl):
     # Routing looks each case's per-slot record up by a one-hot matmul
-    # over the K slots: no gather with a result over the N cases may carry
-    # the route's scope.  splitPre's node_to_slot[case_node] is one, which
-    # shows that the search finds such gathers.
+    # over the K slots, and splitPre gives each case its slot by
+    # subtraction: no gather with a result over the N cases may carry a
+    # frontier scope, the route's least of all.  A plain x[idx] over N
+    # indices shows that the search finds such gathers.
     n = 1200
+    witness = jax.jit(lambda x, idx: x[idx]).lower(
+        jax.ShapeDtypeStruct((64,), jnp.int32),
+        jax.ShapeDtypeStruct((n,), jnp.int32)).compile().as_text()
+    assert [d for _, d in _GATHER.findall(witness) if int(d) == n]
     text, scopes = _small_build_program(impl, n)
-    over_cases = [name for name, dim in _GATHER.findall(text)
-                  if int(dim) == n]
-    assert over_cases
-    assert [g for g in over_cases if scopes[g].scope == "frontier.route"] == []
+    over_cases = [scopes[name].scope if name in scopes else None
+                  for name, dim in _GATHER.findall(text) if int(dim) == n]
+    assert "frontier.route" not in over_cases
+    assert [s for s in over_cases if s and s.startswith("frontier.")] == []
 
 
 def test_hlo_scopes_reads_own_fused_and_neighbouring_scopes():

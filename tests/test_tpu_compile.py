@@ -6,11 +6,13 @@ cannot lower, or a VMEM plan over the limit fails here instead of on the
 chip.  Nothing runs: results and speed come only from ``chip_smoke.py`` on a
 real chip.  Shapes: Table-1 widths at K=256 frontier slots and the tiles
 autotune plans for them; the traversal at the smoke test's forest shape.
-The frontier's case routing is compiled too, to show that the chip's
-compiler leaves it without a gather.
+The frontier's case routing and splitPre are compiled too, to show that the
+chip's compiler leaves neither with a gather over the cases.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import frontier
+from repro.core.config import GrowConfig
 from repro.kernels import autotune, histogram, split_gain, tree_infer
 
 N_CASES = 1 << 20
@@ -118,3 +121,25 @@ def test_route_compiles_for_v5e_without_a_gather(spec):
     compiled = route.lower(per_case, per_case, spec((N_CASES, a), jnp.int32),
                            per_slot, per_slot, per_slot, per_slot).compile()
     assert " gather(" not in compiled.as_text()
+
+
+_GATHER = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[(\d+)[\],].*? gather\(",
+                     re.MULTILINE)
+
+
+def test_split_pre_compiles_for_v5e_without_a_gather_over_the_cases(spec):
+    # splitPre selects the open id range and gives each case its slot by
+    # subtraction: no gather may have a result over the N cases.
+    a, b, c = WIDTHS["syd10m9a"]
+    prob = frontier.FrontierProblem(
+        n_cases=N_CASES, n_attrs=a, n_bins_max=b, n_classes=c,
+        max_children=20, cfg=GrowConfig(max_nodes=1 << 18,
+                                        frontier_slots=SLOTS))
+    state = jax.eval_shape(lambda: frontier.init_state(
+        prob, jnp.zeros((N_CASES,), jnp.int32),
+        jnp.ones((N_CASES,), jnp.float32)))
+    state = jax.tree.map(lambda s: spec(s.shape, s.dtype), state)
+    compiled = jax.jit(functools.partial(frontier.split_pre, prob=prob)
+                       ).lower(state).compile()
+    assert [d for d in _GATHER.findall(compiled.as_text())
+            if int(d) == N_CASES] == []
