@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python benchmarks/exact_witness.py [--cases 50000] [--seed 0]
 
-Grows the SyD10M9A tree (``configs/yadt.py`` grow config) with the
+Grows the SyD10M9A tree (2**18 nodes, 256 slots) with the
 sequential ``c45`` oracle, twice on the same data:
 
   f32   the repository's scorer (``core/entropy.py``) on JAX's default
@@ -34,8 +34,8 @@ import json
 import jax
 import numpy as np
 
-from repro.configs.yadt import WORKLOAD
 from repro.core import c45, entropy
+from repro.core.config import GrowConfig
 from repro.core.tree import trees_equal
 from repro.data import quest
 
@@ -151,7 +151,7 @@ def main() -> None:
     ap.add_argument("--cases", type=int, default=50_000)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
-    cfg = WORKLOAD.grow
+    cfg = GrowConfig(max_nodes=1 << 18, frontier_slots=256)
     ds = quest.syd(args.cases, seed=args.seed)
 
     flips = {"f32_split_f64_leaf": 0, "f32_leaf_f64_split": 0,

@@ -6,7 +6,7 @@ Runs the system's main path once, in this one process, through the entry
 points a user calls, on data generated from ``--seed``:
 
   oracle   SyD10M9A (QUEST function 5) at 50,000 cases with the paper's
-           grow config (``configs/yadt.py``: 2**18 nodes, 256 slots);
+           grow config (2**18 nodes, 256 slots);
            ``c45.build``, ``frontier.build(impl="jnp")`` and
            ``frontier.build(impl="pallas")`` must give equal trees.
   scale    the same schema and config at ``SCALE_CASES`` cases.  First
@@ -112,8 +112,8 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.configs.yadt import WORKLOAD
     from repro.core import c45, frontier
+    from repro.core.config import GrowConfig
     from repro.core.tree import trees_equal
     from repro.data import quest
     from repro.ensemble import ForestConfig, publish_forest, train_forest
@@ -125,7 +125,7 @@ def main() -> int:
     dev = jax.devices()[0]
     print(f"device_kind: {dev.device_kind}")
     print(f"devices: {len(jax.devices())}  compile cache: {cache}")
-    cfg = WORKLOAD.grow
+    cfg = GrowConfig(max_nodes=1 << 18, frontier_slots=256)
     checks = Checks()
 
     def setup_seconds(phase: str, t0: float) -> None:

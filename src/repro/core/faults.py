@@ -1,7 +1,7 @@
 """Deterministic fault injection for the farm and serving stack.
 
 Every failure mode the supervised farm (:mod:`repro.core.farm`) and the
-serving engine (:mod:`repro.serve.engine`) must tolerate can be injected
+predict service (:mod:`repro.infer.service`) must tolerate can be injected
 here, *deterministically*: decisions are a pure hash of
 ``(seed, task key, call number)``, so the same seed produces the same fault
 schedule regardless of thread interleaving — chaos tests are replayable.
@@ -19,9 +19,11 @@ Farm side — :class:`FaultInjector` wraps a ``worker_svc``:
   * ``dead_workers`` — these worker indices die on their first task
                    (a permanently lost core).
 
-Serving side — :class:`ChaosReplica` proxies a ``serve.engine.Replica`` and
-kills it (raises from ``tick``/``admit``) at a chosen tick, so replica
-failover is unit-testable without real hardware faults.
+Serving side — :class:`ChaosReplica` proxies any replica with ``admit`` and
+``tick`` (an :class:`~repro.infer.service.InferReplica`) and kills it (raises
+from ``tick``) at a chosen tick, or fails its first admissions as a
+scheduler race, so replica failover is unit-testable without real hardware
+faults.
 """
 
 from __future__ import annotations
@@ -117,12 +119,13 @@ class FaultInjector:
 
 
 class ChaosReplica:
-    """Proxy a serving ``Replica``; kill it at a chosen engine tick.
+    """Proxy a serving replica; kill it at a chosen service tick.
 
     ``fail_at_tick``  — ``tick()`` raises :class:`InjectedCrash` on the n-th
                         call (1-based) and every call after it.
     ``admit_failures``— the first n ``admit()`` calls raise the scheduler-race
-                        ``RuntimeError`` the engine must absorb by requeueing.
+                        ``RuntimeError`` the service must absorb by
+                        requeueing.
     """
 
     def __init__(self, replica: Any, *, fail_at_tick: int | None = None,

@@ -289,13 +289,11 @@ def split_att(state: GrowState, pre: dict,
               *, prob: FrontierProblem, impl: str) -> dict[str, jnp.ndarray]:
     """The hot phase: fused histogram + gain over (node, attribute)."""
     b_dim = prob.n_bins_max
-    from repro.sharding.act import shard_frontier_hist
     with jax.named_scope("frontier.compact"):
         n_live = jnp.sum((pre["slot"] >= 0).astype(jnp.int32))
     hist_u, n_hist = _histogram(x, y, w, pre["slot"], n_live, prob=prob,
                                 impl=impl)
-    hist_u = shard_frontier_hist(hist_u)                      # (K,A,B+1,C)
-    hist = hist_u[:, :, :b_dim, :]
+    hist = hist_u[:, :, :b_dim, :]                            # (K, A, B, C)
     unknown = hist_u[:, :, b_dim, :]                          # (K, A, C)
     score, split_bin = _gains(
         hist, pre["total_w"], attr_is_cont, n_bins,

@@ -1,24 +1,17 @@
-"""Elastic scaling, failure detection and straggler mitigation (host plane).
+"""Worker health for the supervised farm: liveness and straggler speed.
 
-At 1000+ nodes, three control-plane mechanisms keep a run alive:
+Two monitors watch the farm's workers, and :class:`FarmHealth` turns what
+they see into task placement:
 
-  * :class:`HeartbeatMonitor` — hosts report liveness; a host silent for
-    ``timeout`` seconds is declared failed.  The driver reacts by draining
-    the step, checkpointing (or falling back to the last valid checkpoint),
-    and replanning the mesh without the lost hosts.
-  * :func:`plan_mesh` — given the surviving chip count, pick the largest
-    coherent (pod, data, model) grid that preserves the TP anchor (model=16,
-    the divisibility the whole fleet's layouts are built on) — elastic
-    *data*-parallel width, fixed *model* width.
-  * :class:`StragglerMonitor` — per-step durations; hosts slower than
-    ``factor`` x the running median get flagged.  Host-side work (data
-    shards, eval requests) is rebalanced through the paper's own WS policy
-    (the YaDT-FF weighted scheduler — see core/scheduler.py), which is
-    exactly a straggler-aware least-loaded assignment.
-
-The SPMD step itself is gang-scheduled: failures surface as collective
-timeouts; the driver loop in ``launch/train.py`` wires these pieces to
-checkpoint/restore.
+  * :class:`HeartbeatMonitor` — workers report liveness; one silent for
+    ``timeout`` seconds is declared failed.
+  * :class:`StragglerMonitor` — per-task durations; workers slower than
+    ``factor`` x the running median are flagged, and
+    :meth:`StragglerMonitor.ws_weights` gives each one a speed factor.
+  * :class:`FarmHealth` — the farm's ``health=`` hook: it feeds completed
+    attempts and lost workers into both monitors and produces the
+    :class:`~repro.core.scheduler.HealthWS` policy, the paper's WS weights
+    scaled by observed speed (0 = dead or silent, never scheduled).
 """
 
 from __future__ import annotations
@@ -26,12 +19,9 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import defaultdict, deque
-from typing import Sequence
 
-from repro.core.scheduler import WS, HealthWS, QueueState
+from repro.core.scheduler import HealthWS
 from repro.obs import metrics as obs_metrics
-
-TP_ANCHOR = 16   # model-axis width the fleet's divisibility is built on
 
 
 @dataclasses.dataclass
@@ -72,37 +62,6 @@ class HeartbeatMonitor:
         return [h for h in self.hosts if h not in bad]
 
 
-def plan_mesh(n_chips: int, *, chips_per_pod: int = 256,
-              model: int = TP_ANCHOR) -> tuple[tuple[int, ...],
-                                               tuple[str, ...]]:
-    """Largest usable (pod, data, model) grid for the surviving chips.
-
-    Keeps model = TP_ANCHOR fixed (layout anchor), scales data width down to
-    what the survivors support; multi-pod only when whole pods survive.
-    """
-    if n_chips < model:
-        raise ValueError(f"need at least {model} chips, have {n_chips}")
-    pods = n_chips // chips_per_pod
-    if pods >= 2:
-        usable_pods = pods
-        data = chips_per_pod // model
-        return (usable_pods, data, model), ("pod", "data", "model")
-    data = n_chips // model
-    return (data, model), ("data", "model")
-
-
-def rebatch_for_mesh(global_batch: int, mesh_shape: Sequence[int],
-                     axes: Sequence[str]) -> int:
-    """Nearest feasible global batch for a replanned mesh (keeps per-replica
-    batch constant: elastic batch scaling)."""
-    dp = 1
-    for n, a in zip(mesh_shape, axes):
-        if a in ("pod", "data"):
-            dp *= n
-    per_replica = max(1, global_batch // dp)
-    return per_replica * dp
-
-
 class StragglerMonitor:
     """Flags hosts whose recent step times exceed factor x fleet median."""
 
@@ -141,7 +100,7 @@ class StragglerMonitor:
 
 
 class FarmHealth:
-    """Bridge the farm's execution events into the control plane.
+    """Bridge the farm's execution events into the health monitors.
 
     The supervised farm (:class:`repro.core.farm.Farm`) calls ``on_task``
     per completed attempt and ``on_worker_dead`` per lost worker; this class

@@ -11,7 +11,8 @@ The emitter assigns each outgoing task to a worker queue according to one of:
 
 Policies are pure-Python and deliberately tiny: they are shared by the real
 threaded farm (:mod:`repro.core.farm`), the discrete-event simulator
-(:mod:`repro.core.simulate`) and the serving engine's request dispatcher.
+(:mod:`repro.core.simulate`) and the predict service's batch dispatcher
+(:mod:`repro.infer.service`).
 """
 
 from __future__ import annotations
@@ -103,7 +104,7 @@ class HealthWS(WS):
     """WS scaled by per-worker health: projected-finish-time scheduling.
 
     ``speed_fn`` returns ``{worker_index: speed}`` — the relative throughput
-    factors from :meth:`repro.train.elastic.StragglerMonitor.ws_weights`
+    factors from :meth:`repro.core.health.StragglerMonitor.ws_weights`
     (fleet_median / worker_median; a straggler scores < 1).  A worker's
     effective load is ``(queued_weight + task_weight) / speed``, so slow
     hosts receive proportionally less work.  Speed 0 marks a worker
@@ -140,7 +141,7 @@ def make_policy(name: str, *, speed_fn=None) -> Policy:
     """Policy factory by name: ``drr | od | ws | health_ws``.
 
     ``speed_fn`` is the :class:`HealthWS` hook (``{worker_index: speed}``,
-    e.g. :meth:`repro.train.elastic.FarmHealth.speeds`); with no hook every
+    e.g. :meth:`repro.core.health.FarmHealth.speeds`); with no hook every
     worker scores speed 1.0 and ``health_ws`` degenerates to plain WS.
     """
     name = name.lower()

@@ -1,2 +1,2 @@
-"""Data substrate: QUEST synthetic generator, Table-1 dataset stand-ins,
-and the sharded/resumable LM token pipeline."""
+"""Data substrate: QUEST synthetic generator and the Table-1 dataset
+stand-ins."""

@@ -1,10 +1,8 @@
 """Deterministic per-tree bagging inputs: bootstrap weights + feature subsets.
 
 Every randomised ingredient of a forest member is a *pure function* of
-``(seed, tree_id)`` — the same content-addressed determinism discipline as
-:mod:`repro.data.loader` (batch ``i`` is a pure function of ``(seed, step)``).
-Nothing is sampled at dispatch time and no sampling state lives in the
-trainer, so:
+``(seed, tree_id)``.  Nothing is sampled at dispatch time and no sampling
+state lives in the trainer, so:
 
   * any farm worker can regenerate any tree's inputs after a crash — a
     retried tree task is bit-identical to its first attempt;

@@ -9,8 +9,7 @@ Layout (one directory per model name, one per published version)::
         v00000002/...
         tmp.<ver>.<pid>.<seq>/   # in-flight publish (crashed ones are GC'd)
 
-Publishing follows the same ``tmp.* + os.replace`` discipline as
-:mod:`repro.train.checkpoint`: every file lands in a ``tmp.*`` staging
+Publishing is atomic: every file lands in a ``tmp.*`` staging
 directory and one atomic rename makes the version visible — a crash between
 tmp-write and rename leaves :func:`latest_valid` serving the prior version,
 and the torn staging directory is garbage-collected once it is old enough
@@ -29,6 +28,7 @@ import itertools
 import json
 import os
 import shutil
+import time
 import zipfile
 import zlib
 from typing import Any
@@ -38,12 +38,15 @@ import numpy as np
 
 from repro.core.tree import Tree
 from repro.infer.forest import Forest
-from repro.train.checkpoint import TMP_GC_AGE, gc_stale_tmp
 
 _MANIFEST = "manifest.json"
 _MODEL = "model.npz"
 _PUB_SEQ = itertools.count()
 SCHEMA_VERSION = 1
+
+#: tmp.* directories older than this (seconds) are presumed abandoned by a
+#: crashed publisher and are garbage-collected by :func:`latest_valid`.
+TMP_GC_AGE = 3600.0
 
 #: Forest fields serialized into ``model.npz`` (order is the npz key order).
 _FIELDS = tuple(f.name for f in dataclasses.fields(Forest))
@@ -136,10 +139,33 @@ def _next_version(root: str, name: str) -> int:
     return 1 + (max(nums) if nums else 0)
 
 
+def gc_stale_tmp(directory: str, *, max_age: float = TMP_GC_AGE) -> list[str]:
+    """Delete ``tmp.*`` directories older than ``max_age`` seconds.
+
+    Crashed publishers leave these behind (the atomic ``os.replace``
+    never ran); anything older than ``max_age`` cannot belong to a live
+    publisher and is reclaimed.  Returns the removed paths.
+    """
+    removed = []
+    now = time.time()
+    for d in os.listdir(directory):
+        if not d.startswith("tmp."):
+            continue
+        path = os.path.join(directory, d)
+        try:
+            if now - os.path.getmtime(path) >= max_age:
+                shutil.rmtree(path, ignore_errors=True)
+                removed.append(path)
+        except OSError:
+            continue
+    return removed
+
+
 def latest_valid(root: str, name: str, *,
                  gc_tmp_age: float | None = TMP_GC_AGE) -> str | None:
-    """Newest version passing checksum verification (same contract as
-    ``train.checkpoint.latest_valid``, including stale-``tmp.*`` GC)."""
+    """Newest version passing checksum verification; first removes the
+    ``tmp.*`` staging directories older than ``gc_tmp_age`` seconds
+    (``None`` keeps them)."""
     d = os.path.join(root, name)
     if not os.path.isdir(d):
         return None

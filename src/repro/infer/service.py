@@ -7,17 +7,32 @@ closes the gap with **microbatching**: requests queue per routing arm
 its oldest request has waited ``max_wait_ticks`` engine ticks, trading a
 bounded latency floor for kernel-efficient batch shapes.
 
-Closed batches are *tasks on a farm of replicas*, exactly the paper's
-emitter/worker shape reused a third time (tree build, LM serving, now
-inference): the dispatcher picks a replica per batch with
-:func:`repro.core.scheduler.make_policy` (``drr | od | ws | health_ws``,
-task weight = batch rows), and replica faults follow the
-:mod:`repro.serve.engine` failover contract — a replica whose ``admit`` or
-``tick`` raises is evicted (masked as a zero-capacity view so stateful
-policies keep addressing physical indices), its queued requests are
-re-admitted under a bounded per-request requeue budget, and
-``run_until_drained`` ends every submitted request as exactly one
-:class:`PredictResult` or one :class:`PredictFailure`.
+Closed batches are *tasks on a farm of replicas*, the paper's
+emitter/worker shape reused for inference: the dispatcher picks a replica
+per batch with :func:`repro.core.scheduler.make_policy`
+(``drr | od | ws | health_ws``, task weight = batch rows).  The policy
+always sees the *full* replica list, so a stateful policy (DRR's rotation)
+keeps addressing physical replicas across a failover; ``od`` admits at most
+``Policy.forced_capacity`` (= 1) new batches per replica per tick, and
+never more than the replica's free queue slots.
+
+Failover contract:
+
+  * a replica whose ``tick`` (or ``admit``, other than with the
+    ``RuntimeError`` below) raises is *evicted* — masked as a zero-capacity
+    view, never scheduled again, recorded as an ``infer.replica.evict``
+    instant and in ``infer_evictions_total`` — and the batches it queued
+    are re-admitted at the front of their arm's pending queue;
+  * each re-admission charges the request's requeue budget
+    (``max_requeues``); a request over budget fails as
+    ``requeue_exhausted``;
+  * ``admit`` raising ``RuntimeError`` is a scheduler race (the replica's
+    queue filled after the policy looked): the batch goes back to the ready
+    queue and the replica stays healthy;
+  * ``run_until_drained`` ends every submitted request as exactly one
+    :class:`PredictResult` or one :class:`PredictFailure` — ``no_replicas``
+    once every replica is evicted, ``max_ticks`` for what is still undrained
+    at the tick limit; nothing is dropped silently.
 
 Canary / shadow: a :class:`~repro.infer.registry.ModelHandle` routes each
 uid deterministically to an arm; shadow mode mirrors every dispatched batch
@@ -301,9 +316,13 @@ class BatchPredictService:
 
     # ------------------------------------------------------------- dispatch
     def _dispatch(self) -> None:
-        # Same masking discipline as serve.engine: the policy always sees
-        # the full replica list, with evicted replicas as zero-capacity
-        # views, so stateful policies address physical indices forever.
+        # The policy always sees the full replica list, with evicted
+        # replicas as zero-capacity views, so stateful policies address
+        # physical indices forever.  With a forced-capacity policy (OD),
+        # "queued" means newly admitted this tick: at most forced_capacity
+        # batches per replica, and never more than its free queue slots.
+        forced = getattr(self.policy, "forced_capacity", None)
+        newly = [0] * len(self.replicas)
         while self.ready:
             if not any(self.healthy):
                 return
@@ -311,6 +330,10 @@ class BatchPredictService:
             for i, rep in enumerate(self.replicas):
                 if not self.healthy[i]:
                     views.append(QueueState(tasks=0, weight=0.0, cap=0))
+                elif forced is not None:
+                    views.append(QueueState(
+                        tasks=newly[i], weight=rep.queued_weight(),
+                        cap=min(forced, rep.capacity() - rep.queue_len())))
                 else:
                     views.append(QueueState(tasks=rep.queue_len(),
                                             weight=rep.queued_weight(),
@@ -330,6 +353,7 @@ class BatchPredictService:
                 self._evict(i, f"admit raised: {e!r}")
                 self.ready.appendleft(batch)
                 continue
+            newly[i] += 1
             self._m_batch_rows.observe(len(batch.requests))
             for req in batch.requests:
                 if not self._dispatched.get(req.uid):
@@ -371,9 +395,9 @@ class BatchPredictService:
                           ) -> list[PredictResult]:
         """Tick until every submitted request has a terminal record.
 
-        Mirrors ``serve.engine``: results in ``self.results``, explicit
-        failure records in ``self.failed`` — nothing is dropped silently,
-        including at ``max_ticks`` or after losing the last replica.
+        Results land in ``self.results``, explicit failure records in
+        ``self.failed`` — nothing is dropped silently, including at
+        ``max_ticks`` or after losing the last replica.
         """
         for _ in range(max_ticks):
             if self._inflight == 0:

@@ -24,8 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.sharding import act
-
 
 def bucket_sizes(n_cases: int, *, min_bucket: int = 1024) -> tuple[int, ...]:
     """Static gather-size ladder: powers of two from ``min_bucket`` to N.
@@ -101,9 +99,8 @@ def compact_frontier_histogram(
             with jax.named_scope(scope):
                 idx = jnp.nonzero(part, size=size, fill_value=0)[0]
                 live = jnp.arange(size, dtype=jnp.int32) < n_active
-                xg = act.shard_active_cases(x[idx])
-                sg = act.shard_active_cases(
-                    jnp.where(live, slot[idx], -1).astype(jnp.int32))
+                xg = x[idx]
+                sg = jnp.where(live, slot[idx], -1).astype(jnp.int32)
                 yg, wg = y[idx], w[idx]
             return kernel_ops.frontier_histogram(xg, yg, wg, sg, **kw)
         return run

@@ -16,9 +16,9 @@ repo's three runtimes expose that data:
 Instrumented producers: the supervised farm (:mod:`repro.core.farm`), the
 SPMD frontier engine (:func:`repro.core.frontier.build`: named phases in
 its fused program, per-superstep totals in the registry, profiler
-annotations around its host work), the serving engine
-(:mod:`repro.serve.engine`) and the heartbeat plane
-(:mod:`repro.train.elastic`).  Enabled spans also land in a JAX profiler
+annotations around its host work), the predict service
+(:mod:`repro.infer.service`) and the farm's heartbeat monitor
+(:mod:`repro.core.health`).  Enabled spans also land in a JAX profiler
 trace.  Everything is zero-cost when tracing is disabled: the default
 :data:`repro.obs.trace.NULL` tracer short-circuits every call.
 """

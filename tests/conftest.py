@@ -1,10 +1,10 @@
 """Shared test fixtures.
 
 NOTE: no XLA_FLAGS here on purpose — unit tests must see the real single
-CPU device; only the dry-run (and the subprocess in test_dryrun_small)
-fakes a device count.
+CPU device.
 """
 
+import functools
 import threading
 
 import numpy as np
@@ -62,3 +62,20 @@ def make_tree_dataset(rng, n=300, *, n_cont=2, n_disc=2, n_classes=2,
     y = np.where(gate, (y + 1) % n_classes, y)     # correlate with col 0
     return binning.fit(cols, y, attr_is_cont=kinds, n_classes=n_classes,
                        max_bins=max_bins)
+
+
+# The Table-1 stand-ins at CPU scale, for the oracle grids: wide and
+# discrete-heavy (census_pums, 40 attrs), QUEST-generated (syd10m9a, 9 attrs,
+# continuous-heavy), all-discrete with 5 classes (us_census, 67 attrs of
+# 2-11 values), many-class (kddcup99, 23 classes) and deep (forest_cover).
+STANDINS = [("census_pums", 0.001), ("syd10m9a", 0.00002),
+            ("us_census", 0.001), ("kddcup99", 0.0001),
+            ("forest_cover", 0.001)]
+STANDIN_NAMES = [name for name, _ in STANDINS]
+
+
+@functools.lru_cache(maxsize=None)
+def standin(name):
+    """A stand-in of :data:`STANDINS` at its scale, in 16 bins."""
+    from repro.data import datasets
+    return datasets.load(name, scale=dict(STANDINS)[name], max_bins=16)

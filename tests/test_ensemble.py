@@ -16,7 +16,8 @@ The load-bearing guarantees:
 import numpy as np
 import pytest
 
-from conftest import make_tree_dataset, run_with_timeout
+from conftest import (STANDIN_NAMES, make_tree_dataset, run_with_timeout,
+                      standin)
 from repro.core import faults
 from repro.core.config import GrowConfig
 from repro.core.farm import FaultPolicy
@@ -122,6 +123,20 @@ class TestFarmDeterminism:
         seq = trainer.train_forest_sequential(ds, fc, impl="c45")
         fro = trainer.train_forest_sequential(ds, fc, impl="frontier")
         assert _forests_equal(seq, fro)
+
+    @pytest.mark.parametrize("name", STANDIN_NAMES)
+    def test_farm_trained_frontier_forest_equals_sequential_oracle(self,
+                                                                  name):
+        """The farm's frontier-grown forest == the per-tree c45 oracle's,
+        bootstrap weights and feature masks included."""
+        ds = standin(name)
+        fc = ForestConfig(n_trees=4, seed=1,
+                          grow=GrowConfig(max_nodes=4096, frontier_slots=32))
+        seq = trainer.train_forest_sequential(ds, fc, impl="c45")
+        res = run_with_timeout(
+            lambda: trainer.train_forest(ds, fc, impl="frontier",
+                                         n_workers=2), 240)
+        assert _forests_equal(seq, res.trees)
 
     def test_farm_build_engine_accepts_same_hooks(self):
         """All three engines share the attr_mask/case_w contract."""

@@ -10,10 +10,12 @@ hang into a failure).
 import numpy as np
 import pytest
 
-from conftest import make_tree_dataset, run_with_timeout
+from conftest import (STANDIN_NAMES, make_tree_dataset, run_with_timeout,
+                      standin)
 from repro.core import c45, faults, frontier
 from repro.core.config import GrowConfig
 from repro.core.farm import FaultPolicy
+from repro.core.scheduler import make_policy
 from repro.core.farm_build import QuarantinedNodes, build
 from repro.core.tree import predict, trees_equal
 
@@ -35,6 +37,24 @@ def test_farm_build_matches_oracle_without_faults():
     t_seq = c45.build(ds, CFG)
     t_farm = run_with_timeout(lambda: build(ds, CFG, n_workers=4), 120)
     assert trees_equal(t_seq, t_farm)
+
+
+@pytest.mark.parametrize("policy", ["drr", "od", "ws", "health_ws"])
+@pytest.mark.parametrize("name", STANDIN_NAMES)
+def test_farm_build_matches_oracle_under_every_policy(name, policy):
+    """Every scheduling policy grows the oracle's tree; health_ws, told
+    worker 0 is dead, also never gives it a task."""
+    ds = standin(name)
+    t_seq = c45.build(ds, CFG)
+    stats = {}
+    pol = make_policy(policy, speed_fn=lambda: {0: 0.0})
+    t_farm = run_with_timeout(
+        lambda: build(ds, CFG, n_workers=3, policy=pol, stats_out=stats),
+        120)
+    assert trees_equal(t_seq, t_farm)
+    assert sum(stats["worker_tasks"]) >= t_seq.size - 1 > 0
+    if policy == "health_ws":
+        assert stats["worker_tasks"][0] == 0
 
 
 def test_farm_build_handles_unknowns_and_fractional_weights():

@@ -17,7 +17,7 @@ from repro.core import faults
 from repro.core.farm import (AllWorkersDead, Farm, FaultPolicy, TaskFailure,
                              WorkerCrashed)
 from repro.core.scheduler import OD, WS, HealthWS, QueueState
-from repro.train.elastic import FarmHealth, HeartbeatMonitor, StragglerMonitor
+from repro.core.health import FarmHealth, HeartbeatMonitor, StragglerMonitor
 
 pytestmark = pytest.mark.timeout(120)
 
@@ -218,8 +218,28 @@ def test_injector_probabilities_must_be_sane():
 
 
 # ---------------------------------------------------------------------------
-# elastic wiring: heartbeat + straggler weights into the scheduling path
+# worker health: heartbeat + straggler weights into the scheduling path
 # ---------------------------------------------------------------------------
+
+def test_heartbeat_failure_detection():
+    hb = HeartbeatMonitor(timeout=10.0)
+    hb.beat("h0", 1, now=0.0)
+    hb.beat("h1", 1, now=0.0)
+    hb.beat("h0", 2, now=8.0)
+    assert hb.failed(now=11.0) == ["h1"]
+    assert hb.alive(now=11.0) == ["h0"]
+
+
+def test_straggler_detection_and_ws_weights():
+    sm = StragglerMonitor(factor=1.5)
+    for _ in range(8):
+        sm.record("fast0", 1.0)
+        sm.record("fast1", 1.1)
+        sm.record("slow", 2.5)
+    assert sm.stragglers() == ["slow"]
+    w = sm.ws_weights()
+    assert w["slow"] < w["fast0"]          # slow host gets less work
+
 
 def test_health_ws_biases_away_from_stragglers():
     health = FarmHealth(2)

@@ -6,9 +6,12 @@ split-gain kernel are actually on the hot path — a regression here silently
 reverts splitAtt to the jnp reference and nobody notices until a profile.
 """
 
+import functools
+
 import jax
 import numpy as np
 import pytest
+from conftest import STANDIN_NAMES, standin
 
 from repro.core import c45, frontier
 from repro.core.config import GrowConfig
@@ -38,46 +41,43 @@ def kernel_spies(monkeypatch):
     return calls
 
 
-# Table-1 stand-ins at CPU scale: one wide-schema (40 attrs, discrete-heavy),
-# one QUEST-generated (9 attrs, continuous-heavy, 10M-case original) and
-# one all-discrete (67 attrs of 2-11 values, 5 classes: multiway splits on
-# every level).
-BUNDLED = [("census_pums", 0.001), ("syd10m9a", 0.00002),
-           ("us_census", 0.001)]
+def _cfg(criterion, **kw):
+    return GrowConfig(max_nodes=4096, frontier_slots=32,
+                      compact_min_bucket=64, criterion=criterion, **kw)
 
 
-@pytest.mark.parametrize("name,scale", BUNDLED)
+@functools.lru_cache(maxsize=None)
+def _oracle(name, criterion):
+    ds = standin(name)
+    return c45.build(ds, _cfg(criterion), capacity=4096)
+
+
+@pytest.mark.parametrize("criterion", ["gain", "gain_ratio"])
+@pytest.mark.parametrize("compact", [True, False],
+                         ids=["compact", "no_compact"])
+@pytest.mark.parametrize("name", STANDIN_NAMES)
 def test_pallas_path_uses_both_kernels_and_matches_oracle(
-        name, scale, kernel_spies):
-    ds = datasets.load(name, scale=scale, max_bins=16)
-    cfg = GrowConfig(max_nodes=4096, frontier_slots=32,
-                     compact_min_bucket=64)
-    t_pal = frontier.build(ds, cfg, impl="pallas")
+        name, compact, criterion, kernel_spies):
+    ds = standin(name)
+    t_seq = _oracle(name, criterion)
+    t_pal = frontier.build(ds, _cfg(criterion, compact=compact),
+                           impl="pallas")
 
     assert kernel_spies["histogram"] >= 1, "histogram kernel not on hot path"
     assert kernel_spies["split_gain"] >= 1, "split_gain kernel not on hot path"
-    # with N > min_bucket the compaction ladder has several buckets, and the
-    # switch traces the histogram kernel once per bucket
-    n_buckets = len(compaction.bucket_sizes(ds.n_cases, min_bucket=64))
-    assert n_buckets > 1
-    assert kernel_spies["histogram"] >= n_buckets
+    if compact:
+        # with N > min_bucket the compaction ladder has several buckets, and
+        # the switch traces the histogram kernel once per bucket
+        n_buckets = len(compaction.bucket_sizes(ds.n_cases, min_bucket=64))
+        assert n_buckets > 1
+        assert kernel_spies["histogram"] >= n_buckets
+    else:
+        assert kernel_spies["histogram"] == kernel_spies["split_gain"] == 1
 
-    t_jnp = frontier.build(ds, cfg, impl="jnp")
-    t_seq = c45.build(ds, cfg, capacity=cfg.max_nodes)
     assert trees_equal(t_seq, t_pal), "pallas tree != sequential oracle"
-    assert trees_equal(t_jnp, t_pal), "pallas tree != jnp tree"
     p_seq = np.asarray(predict(t_seq, ds.x, ds.attr_is_cont))
     p_pal = np.asarray(predict(t_pal, ds.x, ds.attr_is_cont))
     assert (p_seq == p_pal).all()
-
-
-def test_pallas_no_compact_also_matches(kernel_spies):
-    ds = datasets.load("census_pums", scale=0.001, max_bins=16)
-    cfg = GrowConfig(max_nodes=4096, frontier_slots=32, compact=False)
-    t_pal = frontier.build(ds, cfg, impl="pallas")
-    assert kernel_spies["histogram"] == kernel_spies["split_gain"] == 1
-    t_seq = c45.build(ds, cfg, capacity=cfg.max_nodes)
-    assert trees_equal(t_seq, t_pal)
 
 
 def test_split_gain_scores_match_jnp_scoring():

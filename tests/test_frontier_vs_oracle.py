@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from conftest import make_tree_dataset
+from conftest import STANDIN_NAMES, make_tree_dataset, standin
 from repro.core import c45, frontier
 from repro.core.config import GrowConfig
 from repro.core.tree import predict, trees_equal
@@ -37,22 +37,68 @@ def test_engines_identical(seed, n, n_cont, n_disc, n_classes, slots,
     assert (p1 == p2).all()
 
 
-def test_capacity_overflow_degrades_gracefully(rng):
-    ds = make_tree_dataset(rng, 500, n_cont=3, n_disc=2, n_classes=3)
+@pytest.mark.parametrize("criterion", ["gain", "gain_ratio"])
+@pytest.mark.parametrize("name", STANDIN_NAMES)
+def test_jnp_path_matches_oracle(name, criterion):
+    ds = standin(name)
+    cfg = GrowConfig(max_nodes=4096, frontier_slots=32, criterion=criterion)
+    t_seq = c45.build(ds, cfg, capacity=cfg.max_nodes)
+    t_ff = frontier.build(ds, cfg, impl="jnp")
+    assert trees_equal(t_seq, t_ff), "jnp tree != sequential oracle"
+    p1 = np.asarray(predict(t_seq, ds.x, ds.attr_is_cont))
+    p2 = np.asarray(predict(t_ff, ds.x, ds.attr_is_cont))
+    assert (p1 == p2).all()
+
+
+def _pruned_prefix(small, full):
+    """True when ``small`` is ``full`` with some subtrees cut to leaves:
+    the two are walked together from the root, every node of ``small``
+    has its ``full`` counterpart's frequencies, class and depth, and every
+    internal one its split."""
+    small, full = small.to_numpy(), full.to_numpy()
+    todo = [(0, 0)]
+    while todo:
+        i, j = todo.pop()
+        if (small.node_class[i] != full.node_class[j]
+                or small.node_depth[i] != full.node_depth[j]
+                or not np.allclose(small.node_freq[i], full.node_freq[j],
+                                   atol=1e-3, rtol=1e-4)):
+            return False
+        n = int(small.node_nchild[i])
+        if n == 0:
+            continue
+        if (n != int(full.node_nchild[j])
+                or small.node_attr[i] != full.node_attr[j]
+                or small.node_split_bin[i] != full.node_split_bin[j]):
+            return False
+        todo += [(int(small.node_child0[i]) + c, int(full.node_child0[j]) + c)
+                 for c in range(n)]
+    return True
+
+
+@pytest.mark.parametrize("name", STANDIN_NAMES)
+def test_capacity_overflow_degrades_gracefully(name):
+    """A node table too small for the tree forces leaves: the result is the
+    oracle's tree with subtrees cut off, never an error."""
+    ds = standin(name)
     cfg = GrowConfig(max_nodes=16, frontier_slots=8)
     tree = frontier.build(ds, cfg)          # must not error
-    assert tree.size <= 16
+    full = c45.build(ds, cfg)
+    assert full.size > 16 >= tree.size
+    assert _pruned_prefix(tree, full)
     pred = np.asarray(predict(tree, ds.x, ds.attr_is_cont))
-    assert pred.shape == (500,)
+    assert pred.shape == (ds.n_cases,)
 
 
-def test_max_depth_respected(rng):
-    ds = make_tree_dataset(rng, 400, n_cont=2, n_disc=2)
-    cfg = GrowConfig(max_depth=3, max_nodes=4096)
+@pytest.mark.parametrize("max_depth", [1, 4])
+@pytest.mark.parametrize("name", STANDIN_NAMES)
+def test_max_depth_respected(name, max_depth):
+    ds = standin(name)
+    cfg = GrowConfig(max_depth=max_depth, max_nodes=4096)
     t_seq = c45.build(ds, cfg, capacity=4096)
     t_ff = frontier.build(ds, cfg)
     assert trees_equal(t_seq, t_ff)
-    assert t_ff.depth <= 3
+    assert t_ff.depth <= max_depth
 
 
 def test_collect_stats_reports_cost_model(rng):

@@ -1,8 +1,10 @@
 """Packed Forest: oracle equality of every impl + persistence round-trip."""
 
+import functools
+
 import numpy as np
 import pytest
-from conftest import make_tree_dataset
+from conftest import STANDIN_NAMES, make_tree_dataset, standin
 
 from repro.core import binning, c45
 from repro.core.config import GrowConfig
@@ -125,6 +127,41 @@ class TestVoting:
                  for impl in IMPLS}
         np.testing.assert_array_equal(preds["ref"], preds["vmap"])
         np.testing.assert_array_equal(preds["ref"], preds["pallas"])
+
+
+@functools.lru_cache(maxsize=None)
+def _standin_trees(name):
+    """8 bootstrap trees of a stand-in, grown by the frontier engine, and
+    each one's ``tree.predict`` of the stand-in's cases."""
+    from repro.ensemble import ForestConfig, trainer
+    ds = standin(name)
+    fc = ForestConfig(n_trees=8, seed=0,
+                      grow=GrowConfig(max_nodes=4096, frontier_slots=32))
+    trees = trainer.train_forest_sequential(ds, fc, impl="frontier")
+    return trees, np.stack([
+        np.asarray(tree_predict(t, ds.x, ds.attr_is_cont)) for t in trees])
+
+
+@pytest.mark.parametrize("n_trees", [1, 8])
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("name", STANDIN_NAMES)
+def test_forest_predict_matches_tree_vote(name, impl, n_trees):
+    """Every impl == a float64 weighted vote over per-tree tree.predict.
+
+    The weights are small integers, so every tally is exact in float32 as
+    in float64 and ties break alike (to the lowest class)."""
+    ds = standin(name)
+    trees, per_tree = _standin_trees(name)
+    trees, per_tree = trees[:n_trees], per_tree[:n_trees]
+    weights = np.arange(n_trees) % 3 + 1.0
+    fo = Forest.pack(trees, weights=weights)
+    tally = np.zeros((ds.n_cases, ds.n_classes))
+    for row, wt in zip(per_tree, weights):
+        tally[np.arange(ds.n_cases), row] += wt
+    got = np.asarray(F.predict(fo, ds.x, ds.attr_is_cont, impl=impl))
+    np.testing.assert_array_equal(got, tally.argmax(-1))
+    if n_trees == 1:
+        np.testing.assert_array_equal(got, per_tree[0])
 
 
 class TestPersistenceRoundTrip:

@@ -76,6 +76,25 @@ def test_crash_between_tmp_write_and_rename(tmp_path, fo, monkeypatch):
     assert not stale.exists()
 
 
+def test_latest_valid_gc_collects_stale_tmp_dirs(tmp_path, fo):
+    v1 = registry.publish(str(tmp_path), "m", fo)
+    # a crashed publisher's leftover: tmp dir that never reached os.replace
+    stale = tmp_path / "m" / "tmp.9.1234.0"
+    stale.mkdir()
+    (stale / "model.npz").write_bytes(b"partial")
+    os.utime(stale, (1.0, 1.0))             # epoch 1970: definitely stale
+    fresh = tmp_path / "m" / "tmp.10.1234.1"   # a live publisher: survives
+    fresh.mkdir()
+    assert registry.latest_valid(str(tmp_path), "m") == v1
+    assert not stale.exists()
+    assert fresh.exists()
+    # gc_tmp_age=None keeps even stale staging dirs
+    stale.mkdir()
+    os.utime(stale, (1.0, 1.0))
+    assert registry.latest_valid(str(tmp_path), "m", gc_tmp_age=None) == v1
+    assert stale.exists()
+
+
 def test_corrupt_newest_falls_back(tmp_path, fo):
     registry.publish(str(tmp_path), "m", fo)
     v2 = registry.publish(str(tmp_path), "m", fo)

@@ -6,6 +6,7 @@ from conftest import make_tree_dataset, run_with_timeout
 
 from repro.core import c45
 from repro.core.config import GrowConfig
+from repro.core.faults import ChaosReplica
 from repro.infer import registry
 from repro.infer.forest import Forest
 from repro.infer.service import (BatchPredictService, InferReplica,
@@ -168,6 +169,160 @@ class TestFailover:
         res = run_with_timeout(svc.run_until_drained)
         assert {r.replica for r in res} <= {1, 2}
         assert len(res) + len(svc.failed) == 40
+
+
+def _chaos(fo, ds, *, max_batches=4, **kw):
+    return ChaosReplica(InferReplica.from_forest(fo, ds.attr_is_cont,
+                                                 max_batches=max_batches),
+                        **kw)
+
+
+def _queued_uids(rep):
+    return [r.uid for b in rep.queue for r in b.requests]
+
+
+def _round(svc):
+    """One scheduling round of a tick, without serving anything."""
+    svc._close_batches()
+    svc._dispatch()
+
+
+class TestSchedulingContract:
+    """Policy, race and drain semantics over faulting replicas."""
+
+    def test_health_ws_speed_fn_hook_steers_batches(self, ds, fo):
+        reps = [_chaos(fo, ds, max_batches=8) for _ in range(2)]
+        svc = BatchPredictService(reps, policy="health_ws",
+                                  speed_fn=lambda: {0: 0.0, 1: 1.0},
+                                  max_batch=4, max_wait_ticks=1)
+        _submit(svc, ds, 16)
+        _round(svc)
+        assert _queued_uids(reps[0]) == []      # speed 0 = do not schedule
+        assert _queued_uids(reps[1]) == list(range(16))
+        res = run_with_timeout(svc.run_until_drained)
+        assert {r.replica for r in res} == {1}
+        want = _expected(ds, fo, range(16))
+        assert sorted(r.uid for r in res) == list(range(16))
+        assert all(r.label == want[r.uid] for r in res)
+
+    def test_od_admits_at_most_one_batch_per_replica_per_tick(self, ds, fo):
+        reps = [_chaos(fo, ds) for _ in range(2)]
+        svc = BatchPredictService(reps, policy="od", max_batch=2,
+                                  max_wait_ticks=0)
+        admitted = []
+        for i, rep in enumerate(reps):
+            def admit(batch, i=i, inner=rep.admit):
+                admitted.append((svc._tick, i))
+                return inner(batch)
+            rep.admit = admit
+        _submit(svc, ds, 16)                    # 8 two-row batches
+        _round(svc)
+        assert [rep.queue_len() for rep in reps] == [1, 1]
+        assert len(svc.ready) == 6
+        res = run_with_timeout(svc.run_until_drained)
+        assert sorted(r.uid for r in res) == list(range(16))
+        assert svc.failed == []
+        per_tick = {}
+        for key in admitted:
+            per_tick[key] = per_tick.get(key, 0) + 1
+        assert len(admitted) == 8 and max(per_tick.values()) == 1
+
+    def test_od_respects_free_queue_slots(self, ds, fo):
+        rep = _chaos(fo, ds, max_batches=1)
+        svc = BatchPredictService([rep], policy="od", max_batch=4,
+                                  max_wait_ticks=0)
+        _submit(svc, ds, 8)                     # two 4-row batches
+        _round(svc)
+        assert _queued_uids(rep) == [0, 1, 2, 3]
+        _round(svc)                             # still full, a fresh round
+        assert _queued_uids(rep) == [0, 1, 2, 3]
+        assert len(svc.ready) == 1
+        res = run_with_timeout(svc.run_until_drained)
+        assert sorted(r.uid for r in res) == list(range(8))
+
+    def test_drr_rotation_addresses_physical_replicas_after_eviction(
+            self, ds, fo):
+        reps = [_chaos(fo, ds, max_batches=8) for _ in range(3)]
+        svc = BatchPredictService(reps, policy="drr", max_batch=1,
+                                  max_wait_ticks=0)
+        _submit(svc, ds, 2)
+        _round(svc)                  # DRR: -> r0, r1; the pointer is at r2
+        assert [_queued_uids(r) for r in reps] == [[0], [1], []]
+        svc._evict(0, "test")        # requeues uid 0 at the front
+        _submit(svc, ds, 1, start=2)
+        _round(svc)
+        # the pointer means physical replica 2, and the rotation then skips
+        # the evicted r0
+        assert _queued_uids(reps[2]) == [0]
+        assert _queued_uids(reps[1]) == [1, 2]
+        res = run_with_timeout(svc.run_until_drained)
+        assert sorted(r.uid for r in res) == [0, 1, 2]
+        assert {r.replica for r in res} == {1, 2}
+
+    def test_drr_stays_fair_across_eviction(self, ds, fo):
+        reps = [_chaos(fo, ds, max_batches=16) for _ in range(3)]
+        svc = BatchPredictService(reps, policy="drr", max_batch=1,
+                                  max_wait_ticks=0)
+        svc._evict(1, "test")
+        _submit(svc, ds, 8)
+        _round(svc)
+        assert _queued_uids(reps[1]) == []
+        assert len(_queued_uids(reps[0])) == len(_queued_uids(reps[2])) == 4
+
+    def test_admit_race_requeues_instead_of_crashing(self, ds, fo):
+        tracer = Tracer(enabled=True)
+        rep = _chaos(fo, ds, admit_failures=2)
+        svc = BatchPredictService([rep], max_batch=4, max_wait_ticks=1,
+                                  max_requeues=0, tracer=tracer)
+        _submit(svc, ds, 12)
+        res = run_with_timeout(svc.run_until_drained)
+        assert sorted(r.uid for r in res) == list(range(12))
+        assert svc.failed == []
+        assert svc.healthy == [True]            # a race is not a death
+        assert svc.stats()["requeues"] == 0     # and costs no budget
+        races = [e for e in tracer._events
+                 if e.get("name") == "infer.batch.race"]
+        assert len(races) == 2
+
+    def test_max_ticks_reports_undrained_requests(self, ds, fo):
+        svc = BatchPredictService([_chaos(fo, ds, max_batches=1)],
+                                  max_batch=2, max_wait_ticks=0)
+        _submit(svc, ds, 10)                    # 5 batches, 1 served a tick
+        res = svc.run_until_drained(max_ticks=2)
+        assert len(res) == 4
+        assert sorted([r.uid for r in res] + [f.uid for f in svc.failed]) \
+            == list(range(10))
+        assert {f.reason for f in svc.failed} == {"max_ticks"}
+        assert len(svc.failed) == 6
+
+    def test_eviction_records_instant_and_metric(self, ds, fo):
+        tracer = Tracer(enabled=True)
+        reg = Registry()
+        reps = [_chaos(fo, ds, fail_at_tick=2), _chaos(fo, ds)]
+        svc = BatchPredictService(reps, policy="drr", max_batch=4,
+                                  max_wait_ticks=1, tracer=tracer,
+                                  metrics=reg)
+        _submit(svc, ds, 32)
+        res = run_with_timeout(svc.run_until_drained)
+        evicts = [e for e in tracer._events
+                  if e.get("name") == "infer.replica.evict"]
+        assert [e["args"]["replica"] for e in evicts] == [0]
+        assert "injected replica death" in evicts[0]["args"]["detail"]
+        assert reg.get("infer_evictions_total").value() == 1
+        assert svc.healthy == [False, True]
+        want = _expected(ds, fo, range(32))
+        assert sorted(r.uid for r in res) == list(range(32))
+        assert all(r.label == want[r.uid] for r in res)
+
+    def test_failure_breakdown_stats(self, ds, fo):
+        svc = BatchPredictService([_chaos(fo, ds, fail_at_tick=1)],
+                                  max_batch=4, max_wait_ticks=1)
+        _submit(svc, ds, 8)
+        run_with_timeout(svc.run_until_drained)
+        s = svc.stats()
+        assert s["results"] == 0 and s["failed"] == 8
+        assert sum(s["failed_by_reason"].values()) == 8
+        assert s["evicted_replicas"] == [0]
 
 
 class TestCanaryShadow:

@@ -119,37 +119,3 @@ def test_pallas_engine_end_to_end(rng):
     t_seq = c45.build(ds, cfg, capacity=2048)
     t_pal = frontier.build(ds, cfg, impl="pallas")
     assert trees_equal(t_seq, t_pal)
-
-
-FLASH_CASES = [
-    # (B, S, H, KV, D, window, softcap, dtype)
-    (2, 24, 4, 2, 16, 0, 0.0, "float32"),
-    (1, 33, 4, 4, 8, 0, 0.0, "float32"),      # MHA + ragged padding
-    (2, 24, 4, 2, 16, 7, 0.0, "float32"),     # sliding window
-    (2, 24, 4, 2, 16, 0, 30.0, "float32"),    # softcap (gemma2)
-    (2, 40, 6, 2, 32, 9, 50.0, "float32"),    # window + softcap + GQA 3
-    (2, 32, 4, 2, 16, 0, 0.0, "bfloat16"),
-]
-
-
-@pytest.mark.parametrize("case", FLASH_CASES)
-def test_pallas_flash_attention_matches_jnp(case):
-    import jax.numpy as jnp
-    from repro.kernels.flash_attention import flash_attention
-    from repro.models import layers
-    from repro.models.layers import AttnSpec
-    b, s, h, kv, d, window, cap, dtype = case
-    rng = np.random.default_rng(b * s)
-    dt = jnp.dtype(dtype)
-    q = jnp.asarray(rng.normal(0, 1, (b, s, h, d)), dt)
-    k = jnp.asarray(rng.normal(0, 1, (b, s, kv, d)), dt)
-    v = jnp.asarray(rng.normal(0, 1, (b, s, kv, d)), dt)
-    spec = AttnSpec(n_heads=h, n_kv_heads=kv, head_dim=d, d_model=h * d,
-                    window=window, softcap=cap, dtype=dt)
-    want = layers.blockwise_attention(q, k, v, spec=spec, q_chunk=8,
-                                      kv_chunk=8)
-    got = flash_attention(q, k, v, window=window, softcap=cap, q_chunk=8,
-                          kv_chunk=8, interpret=True)
-    tol = 3e-5 if dtype == "float32" else 2e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), atol=tol)
