@@ -9,6 +9,10 @@ points a user calls, on data generated from ``--seed``:
            grow config (2**18 nodes, 256 slots);
            ``c45.build``, ``frontier.build(impl="jnp")`` and
            ``frontier.build(impl="pallas")`` must give equal trees.
+  compact  the stream-compaction kernel's live-case ids against
+           ``jnp.nonzero`` at both benchmark cells' case counts, for a
+           sparse and a clustered live set (the Mosaic lowering, which
+           interpret mode cannot check).
   scale    the same schema and config at ``SCALE_CASES`` cases.  First
            both splitAtt kernels are checked against the jnp path on a full
            256-slot frontier (equal counts; split-gain scores and bins equal
@@ -51,6 +55,8 @@ SCALE_CASES = 4_000_000
 FOREST_TREES = 8
 REQUESTS = 1_000
 UNKNOWN_FRAC = 0.05
+# The case counts of the benchmark's grow cells (syd10m9a, us_census).
+COMPACT_CASES = (500_000, 245_828)
 
 
 def _use_checkout_src() -> None:
@@ -173,6 +179,28 @@ def main() -> int:
                           f"split_gain ({crit}) equals entropy bit for bit "
                           f"({differ} of {s_k.size} scores differ)")
 
+    def compaction_parity(seed: int) -> None:
+        """live_case_ids equals nonzero at the largest gather bucket, for a
+        sparse live set (3% of cases, scattered) and a clustered one (one
+        run of 20,000 cases and a tail of 3,000)."""
+        from repro.kernels import compaction, ops
+        rng = np.random.default_rng(seed)
+        for n in COMPACT_CASES:
+            size = compaction.bucket_sizes(
+                n, min_bucket=cfg.compact_min_bucket)[-2]
+            clustered = np.zeros(n, bool)
+            clustered[n // 3:n // 3 + 20_000] = True
+            clustered[-3_000:] = True
+            for name, live in (("sparse", rng.random(n) < 0.03),
+                               ("clustered", clustered)):
+                slot = jnp.asarray(np.where(live, rng.integers(0, 256, n),
+                                            -1).astype(np.int32))
+                want = jnp.nonzero(slot >= 0, size=size, fill_value=0)[0]
+                got = ops.live_case_ids(slot, size=size)
+                checks.expect(bool(jnp.array_equal(got, want)),
+                              f"live_case_ids equals nonzero at {n} cases, "
+                              f"{name} ({int(live.sum())} live, {size} ids)")
+
     def no_forced_leaves(ds, tree) -> bool:
         # A superstep adds at most slots * widest-split children, so a tree
         # that ends this far below capacity never hit the overflow guard.
@@ -205,10 +233,15 @@ def main() -> int:
     checks.expect(root_w > 2 ** 8, f"histogram counts above 2^8 (root weight "
                   f"{root_w:.0f})")
     found = _kernels_in(build_lowering(ds, "pallas"),
-                        ("frontier_histogram", "split_gain"))
+                        ("frontier_histogram", "split_gain", "live_case_ids"))
     checks.expect(all(found.values()),
                   f"build lowers to tpu_custom_call kernels {found}")
     setup_seconds("oracle", t0)
+
+    # ---- compaction phase ---------------------------------------------------
+    t0 = time.perf_counter()
+    compaction_parity(args.seed)
+    setup_seconds("compact", t0)
 
     # ---- scale phase --------------------------------------------------------
     t0 = time.perf_counter()

@@ -82,6 +82,14 @@ def infer_vmem_bytes(*, block_n: int, capacity: int, chunk: int,
     return 2 * io + temps
 
 
+def compact_vmem_bytes(*, block_rows: int, chunk: int) -> int:
+    io = tile_bytes(block_rows, LANE) + tile_bytes(chunk // LANE, LANE)
+    temps = (3 * tile_bytes(2 * LANE, LANE)               # window iota, one-hot
+             + 3 * tile_bytes(LANE, LANE)                 # prefix matrices
+             + 12 * tile_bytes(16, LANE))                 # (8|16, T) values
+    return 2 * io + temps
+
+
 def check_vmem(nbytes: int, what: str) -> None:
     if nbytes > VMEM_BUDGET:
         raise VmemError(
@@ -131,6 +139,30 @@ def plan_blocks(
                                  n_classes=c) <= VMEM_BUDGET] or ts[-1:]
         block_t = ts[0]
     return BlockPlan(block_t=block_t, block_k=block_k)
+
+
+#: Rows of 128 cases the compaction kernel streams per grid step, and the
+#: most ids one pass of it keeps in VMEM (4 MiB of int32).
+COMPACT_ROWS = 64
+COMPACT_CHUNK = 1 << 20
+
+
+@dataclasses.dataclass(frozen=True)
+class CompactPlan:
+    """Static tiles of :func:`repro.kernels.compaction.live_case_ids`: case
+    rows per grid step, and ids per output pass (a multiple of 8 rows)."""
+    block_rows: int
+    chunk: int
+
+
+def plan_compact(*, n_cases: int, size: int) -> CompactPlan:
+    """The case rows stream :data:`COMPACT_ROWS` at a time (all of them for
+    a smaller problem); the ids come out in passes of at most
+    :data:`COMPACT_CHUNK`, so VMEM does not grow with N."""
+    rows = round_up(max(1, -(-int(n_cases) // LANE)), SUBLANE)
+    return CompactPlan(
+        block_rows=min(COMPACT_ROWS, rows),
+        chunk=min(COMPACT_CHUNK, round_up(size, SUBLANE * LANE)))
 
 
 @dataclasses.dataclass(frozen=True)

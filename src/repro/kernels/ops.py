@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import autotune
+from repro.kernels import compaction as _compaction
 from repro.kernels import histogram as _histogram
 from repro.kernels import split_gain as _split_gain
 from repro.kernels import tree_infer as _tree_infer
@@ -41,6 +42,18 @@ def frontier_histogram(x, y, w, slot, *, n_slots: int, n_bins: int,
         block_t=block_t, block_k=block_k, interpret=_interpret())
 
 
+def live_case_ids(slot, *, size: int) -> jnp.ndarray:
+    """(size,) ascending ids of the cases with ``slot >= 0``, padded with 0
+    -- ``jnp.nonzero(slot >= 0, size=size, fill_value=0)[0]`` by a
+    scatter-free stream-compaction kernel."""
+    plan = autotune.plan_compact(n_cases=slot.shape[0], size=size)
+    autotune.check_vmem(autotune.compact_vmem_bytes(
+        block_rows=plan.block_rows, chunk=plan.chunk), "live_case_ids")
+    return _compaction.live_case_ids(
+        slot, size=size, block_rows=plan.block_rows, chunk=plan.chunk,
+        interpret=_interpret())
+
+
 def frontier_histogram_compact(x, y, w, slot, *, n_slots: int, n_bins: int,
                                n_classes: int, min_bucket: int = 1024,
                                block_t: int | None = None,
@@ -53,8 +66,7 @@ def frontier_histogram_compact(x, y, w, slot, *, n_slots: int, n_bins: int,
     :mod:`repro.kernels.compaction`).  ``scope`` names the gather's
     operations for the profiler.
     """
-    from repro.kernels import compaction
-    return compaction.compact_frontier_histogram(
+    return _compaction.compact_frontier_histogram(
         x, y, w, slot, n_slots=n_slots, n_bins=n_bins, n_classes=n_classes,
         min_bucket=min_bucket, block_t=block_t, block_k=block_k, scope=scope)
 

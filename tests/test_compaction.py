@@ -71,6 +71,72 @@ def test_compact_gather_is_dense():
         jnp.where(live, slot[idx], -1)).tolist() == [2, 0, 1, -1]
 
 
+def _live_slots(case, n):
+    """Slots of ``n`` cases for one liveness pattern (-1 = not live)."""
+    rng = np.random.default_rng(n)
+    live = np.zeros(n, bool)
+    if case == "one":
+        live[n // 2 + 1] = True
+    elif case == "all":
+        live[:] = True
+    elif case == "last_tile":          # live only in the last 128-case row
+        live[-(n % 128 or 128):] = rng.random(n % 128 or 128) < 0.5
+    elif case.startswith("count="):    # exactly this many, scattered
+        live[rng.choice(n, int(case[6:]), replace=False)] = True
+    elif case == "sparse":
+        live = rng.random(n) < 0.03
+    return np.where(live, rng.integers(0, 7, n), -1).astype(np.int32)
+
+
+# (case, N, S): N = 3,000 and 2,500 are not multiples of the 128-case row or
+# the 1,024-case group; 512 and 1,024 are bucket edges of a 64-case ladder.
+LIVE_CASES = [("none", 3000, 2048), ("one", 3000, 2048), ("all", 3000, 2048),
+              ("last_tile", 3000, 2048), ("count=512", 3000, 2048),
+              ("count=513", 3000, 2048), ("count=1024", 2500, 1024),
+              ("count=1025", 2500, 1024), ("sparse", 2500, 1024),
+              ("all", 2500, 64), ("count=300", 300, 256)]
+
+
+@pytest.mark.parametrize("tiles", ["planned", "small"])
+@pytest.mark.parametrize("case,n,size", LIVE_CASES,
+                         ids=[f"{c}-n{n}-s{s}" for c, n, s in LIVE_CASES])
+def test_live_case_ids_equal_nonzero(case, n, size, tiles):
+    """The stream-compaction kernel gives ``nonzero``'s ids exactly: live
+    ids ascending, then 0.  ``small`` tiles stream 1,024 cases per grid step
+    and write the output in passes of 1,024 ids, so live runs cross steps
+    and passes."""
+    slot = jnp.asarray(_live_slots(case, n))
+    want = np.asarray(jnp.nonzero(slot >= 0, size=size, fill_value=0)[0])
+    if tiles == "planned":
+        got = ops.live_case_ids(slot, size=size)
+    else:
+        got = compaction.live_case_ids(slot, size=size, block_rows=8,
+                                       chunk=compaction.GROUP,
+                                       interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_live_case_ids_refuses_unaligned_tiles():
+    slot = jnp.zeros((256,), jnp.int32)
+    with pytest.raises(ValueError, match="multiple"):
+        compaction.live_case_ids(slot, size=64, block_rows=4, chunk=1024,
+                                 interpret=True)
+    with pytest.raises(ValueError, match="multiple"):
+        compaction.live_case_ids(slot, size=64, block_rows=8, chunk=512,
+                                 interpret=True)
+
+
+def test_compact_plan_keeps_vmem_flat_in_n():
+    for n, size in [(300, 256), (500_000, 262_144), (245_828, 131_072),
+                    (10_000_000, 8_388_608)]:
+        p = autotune.plan_compact(n_cases=n, size=size)
+        assert p.block_rows % 8 == 0 and p.chunk % compaction.GROUP == 0
+        assert p.block_rows * 128 <= autotune.round_up(n, 1024)
+        assert size <= p.chunk or p.chunk == autotune.COMPACT_CHUNK
+        assert autotune.compact_vmem_bytes(
+            block_rows=p.block_rows, chunk=p.chunk) <= autotune.VMEM_BUDGET
+
+
 def test_autotune_respects_budget_and_extents():
     for n, k, b, c, a in [(100, 4, 3, 2, 2), (1 << 20, 256, 128, 23, 41),
                           (50_000, 64, 64, 7, 54),

@@ -6,6 +6,7 @@ split-gain kernel are actually on the hot path — a regression here silently
 reverts splitAtt to the jnp reference and nobody notices until a profile.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -78,6 +79,38 @@ def test_pallas_path_uses_both_kernels_and_matches_oracle(
     p_seq = np.asarray(predict(t_seq, ds.x, ds.attr_is_cont))
     p_pal = np.asarray(predict(t_pal, ds.x, ds.attr_is_cont))
     assert (p_seq == p_pal).all()
+
+
+@pytest.fixture
+def small_compaction_tiles(monkeypatch):
+    """The compaction kernel streams 1,024 cases per grid step and writes
+    1,024 ids per pass, so a few thousand cases cross both."""
+    from repro.kernels import autotune
+    monkeypatch.setattr(autotune, "COMPACT_ROWS", 8)
+    monkeypatch.setattr(autotune, "COMPACT_CHUNK", compaction.GROUP)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("name,scale", [("syd10m9a", 0.0003),
+                                        ("us_census", 0.001)])
+def test_compacted_build_equals_uncompacted_across_tiles(
+        name, scale, small_compaction_tiles):
+    """compact=True grows the compact=False tree node for node while its
+    live cases cross the kernel's grid steps in several gather buckets (on
+    syd10m9a up to the 2,048-case bucket, whose ids take two passes)."""
+    ds = datasets.load(name, scale=scale, max_bins=16)
+    cfg = GrowConfig(max_nodes=4096, frontier_slots=32,
+                     compact_min_bucket=64)
+    t_compact, rows = frontier.build(ds, cfg, impl="pallas",
+                                     collect_stats=True)
+    t_full = frontier.build(ds, dataclasses.replace(cfg, compact=False),
+                            impl="pallas")
+    buckets = {r["n_hist"] for r in rows if r["n_hist"] < ds.n_cases}
+    assert ds.n_cases > 2 * compaction.GROUP
+    assert len(buckets) >= 3 and max(buckets) >= compaction.GROUP, buckets
+    assert trees_equal(t_compact, t_full)
 
 
 def test_split_gain_scores_match_jnp_scoring():

@@ -21,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import frontier
 from repro.core.config import GrowConfig
-from repro.kernels import autotune, histogram, split_gain, tree_infer
+from repro.kernels import (autotune, compaction, histogram, split_gain,
+                           tree_infer)
 
 N_CASES = 1 << 20
 SLOTS = 256
@@ -93,6 +94,20 @@ def test_split_gain_compiles_for_v5e(spec, width):
         spec((a,), jnp.bool_), spec((a,), jnp.int32),
         criterion="gain_ratio", block_k=plan.block_k).compile()
     _assert_mosaic(compiled, "split_gain")
+
+
+@pytest.mark.parametrize("n_cases", [N_CASES, 4_000_000])
+def test_live_case_ids_compiles_for_v5e(spec, n_cases):
+    # the largest gather bucket of the ladder; at 4M cases its 2**21 ids
+    # come out in two passes of the kernel
+    size = compaction.bucket_sizes(n_cases)[-2]
+    plan = autotune.plan_compact(n_cases=n_cases, size=size)
+    autotune.check_vmem(autotune.compact_vmem_bytes(
+        block_rows=plan.block_rows, chunk=plan.chunk), "live_case_ids")
+    compiled = compaction.live_case_ids.lower(
+        spec((n_cases,), jnp.int32), size=size, block_rows=plan.block_rows,
+        chunk=plan.chunk).compile()
+    _assert_mosaic(compiled, "live_case_ids")
 
 
 def test_traversal_compiles_for_v5e(spec):
