@@ -1,6 +1,7 @@
 """Device ms per tree in the fused build's ``frontier.compact`` scope,
 scopes nested in it included: compaction: the live-case count, the
-``nonzero`` over the cases and the gathers (``bench/scopes.py``)."""
+``live_case_ids`` stream compaction of the live case ids and the gathers
+(``bench/scopes.py``)."""
 
 from bench import scopes
 

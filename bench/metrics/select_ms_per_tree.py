@@ -1,6 +1,7 @@
 """Device ms per tree in the fused build's ``frontier.select`` scope, scopes
-nested in it included: the selection of open nodes over the node-status
-array and the loop's test for open nodes (``bench/scopes.py``)."""
+nested in it included: the frontier as the open id range ``[open_nodes,
+n_nodes)`` and the loop's test ``open_nodes < n_nodes``
+(``bench/scopes.py``)."""
 
 from bench import scopes
 

@@ -1,7 +1,8 @@
-"""Device busy time per tree outside the Pallas kernels (splitPre, the
-compaction gather, splitPost and the copies), in ms."""
+"""Device busy time per tree outside the Pallas kernels
+(``frontier_histogram``, ``split_gain``, ``live_case_ids``): splitPre, the
+compaction gathers, splitPost and the copies, in ms."""
 
-KERNELS = ("frontier_histogram", "split_gain")
+KERNELS = ("frontier_histogram", "split_gain", "live_case_ids")
 
 
 def read(ctx):

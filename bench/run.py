@@ -7,6 +7,8 @@ Run from the root of a checkout.  The cell is looked up in
 ``BENCHMARK.json``; everything that belongs to it is found by name:
 
   bench/configs/<config>.json   the deployment: data, grow config, limits
+  bench/generators/<generator>.py   the training set a configuration's
+                                    data block names
   bench/traffic/<traffic>.json  the mix; its ``mode`` names the driver
   bench/modes/<mode>.py         the driver: set-up, window, correctness
   bench/metrics/<metric>.py     one reader per per-layer metric
@@ -53,7 +55,7 @@ def load(path: Path) -> dict:
 
 
 def load_module(path: Path, name: str):
-    """Import a mode or metric file once, by its path."""
+    """Import a mode, generator or metric file once, by its path."""
     if name in sys.modules:
         return sys.modules[name]
     spec = importlib.util.spec_from_file_location(name, path)
@@ -289,4 +291,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # Files loaded by path import the harness as ``bench.run``: let that be
+    # this module, so that the BenchError they raise is the one main() catches.
+    sys.modules["bench.run"] = sys.modules[__name__]
     sys.exit(main())

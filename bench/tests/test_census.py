@@ -6,8 +6,8 @@ from pathlib import Path
 
 import pytest
 
-from bench import data
 from bench import run as harness
+from bench.generators.table1_standin import standin_columns
 
 CONFIG = Path(__file__).resolve().parents[1] / "configs" / "us_census.json"
 CASES = 3000
@@ -17,8 +17,8 @@ PEAK_KIND = "TPU v5 lite"
 def test_census_config_pins_the_drawn_cardinalities():
     spec = json.loads(CONFIG.read_text())["data"]
     assert spec["n_cases"] == 245_828
-    _, drawn, _ = data.standin_columns(spec["n_cases"], name="us_census",
-                                       seed=spec["data_seed"], n_attrs=67)
+    _, drawn, _ = standin_columns(spec["n_cases"], name="us_census",
+                                  seed=spec["data_seed"], n_attrs=67)
     assert spec["cardinalities"] == drawn
     assert max(drawn) == 11
 
@@ -33,7 +33,7 @@ def small(monkeypatch):
         d = load(path)
         if path == CONFIG:
             d["data"]["n_cases"] = CASES
-            _, d["data"]["cardinalities"], _ = data.standin_columns(
+            _, d["data"]["cardinalities"], _ = standin_columns(
                 CASES, name="us_census", seed=d["data"]["data_seed"],
                 n_attrs=67)
         return d

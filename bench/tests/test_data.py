@@ -3,11 +3,12 @@
 import numpy as np
 
 from bench import data
+from bench.generators.quest5 import quest5
 
 
 def test_quest5_equals_the_program_generator():
     from repro.data import quest
-    got = data.quest5(20_000, seed=3, max_bins=256)
+    got = quest5(20_000, seed=3, max_bins=256)
     want = quest.syd(20_000, seed=3)
     assert np.array_equal(got["x"], want.x)
     assert np.array_equal(got["y"], want.y)
@@ -29,7 +30,7 @@ def test_census_standin_equals_the_program_generator():
 
 
 def test_permutation_keeps_the_cases():
-    ds = data.quest5(1000, seed=0, max_bins=256)
+    ds = quest5(1000, seed=0, max_bins=256)
     p = data.permuted(ds, 2**31 + 11)
     assert not np.array_equal(p["x"], ds["x"])
     rows = lambda d: sorted(map(tuple, np.c_[d["x"], d["y"]]))  # noqa: E731
