@@ -606,6 +606,20 @@ class _ScoredPairs:
         return float(np.sum(active[~stopped]))
 
 
+@dataclasses.dataclass(frozen=True)
+class _PresentClasses:
+    """The (slot, class) columns of a build's histograms that held a case,
+    counted on the host from its tree when ``float()`` reads them: each
+    grown node took a slot once, and its columns that held a case are the
+    classes of weight above ``EPS_W`` in its counts."""
+
+    tree: Tree
+
+    def __float__(self) -> float:
+        t = self.tree.to_numpy()
+        return float(np.sum(t.node_freq[: int(t.n_nodes)] > EPS_W))
+
+
 def _publish(state: GrowState, prob: FrontierProblem, scored: _ScoredPairs,
              reg: obs_metrics.Registry) -> None:
     """The one writer of the ``frontier_*`` gauges: the last build's totals,
@@ -630,6 +644,11 @@ def _publish(state: GrowState, prob: FrontierProblem, scored: _ScoredPairs,
               "frontier slots of the last build").set(prob.cfg.frontier_slots)
     reg.gauge("frontier_attrs",
               "attributes of the last build").set(prob.n_attrs)
+    reg.gauge("frontier_present_classes",
+              "sum over the last build's slotted nodes of the classes in "
+              "its counts").set(_PresentClasses(scored.tree))
+    reg.gauge("frontier_classes",
+              "classes of the last build").set(prob.n_classes)
 
 
 def build(ds: BinnedDataset, cfg: GrowConfig = GrowConfig(), *,
@@ -642,8 +661,9 @@ def build(ds: BinnedDataset, cfg: GrowConfig = GrowConfig(), *,
     The build is one jitted ``while_loop`` over supersteps.  It carries its
     totals (supersteps, open nodes processed, cases in an open node and
     cases the histogram was given, summed over supersteps) and writes them,
-    the (slot, attribute) pairs its supersteps scored (counted on the host
-    from the tree when read), N, K and A to the ``frontier_*`` gauges of ``metrics``
+    the (slot, attribute) pairs its supersteps scored and the (slot, class)
+    columns that held a case (both counted on the host from the tree when
+    read), N, K, A and C to the ``frontier_*`` gauges of ``metrics``
     (default the process-wide :data:`repro.obs.metrics.REGISTRY`) without
     waiting for the device.  Its host work runs under the profiler
     annotations ``frontier.build`` and ``frontier.to_device`` (the copy of
